@@ -30,10 +30,16 @@ wire_frac) and the d2-vs-d1 speedup. The ``vfl_tower_roofline_*``
 rows are informational (per-step compute seconds per role).
 
 Standalone: PYTHONPATH=src python -m benchmarks.bench_tower [--quick]
+
+A host-CPU bench: it pins JAX to the CPU (``JAX_PLATFORMS=cpu``, set
+before JAX is imported) so its ``socket_proc`` agents, which inherit the
+setting, never contend for a chip.
 """
 from __future__ import annotations
 
 import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import numpy as np
 
@@ -140,5 +146,6 @@ if __name__ == "__main__":
     def emit(name, us, derived):
         print(f"{name},{us:.2f},{derived}")
 
+    print("# host-CPU bench: JAX_PLATFORMS=cpu; no row is a device number")
     print("name,us_per_call,derived")
     bench_tower(emit, args.quick)

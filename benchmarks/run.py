@@ -11,8 +11,17 @@ Paper artifacts covered:
               bench_roofline (dry-run roofline terms per arch x shape)
 
 Run: PYTHONPATH=src python -m benchmarks.run [--quick]
+
+This is the host-CPU bench: it pins JAX to the CPU (``JAX_PLATFORMS=cpu``,
+set before JAX is imported, so the agent processes it spawns inherit it)
+and its Pallas kernel rows run in interpret mode. None of its numbers is
+a device number; ``chip_smoke.py`` is the path that runs on the chip.
 """
 from __future__ import annotations
+
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse
 import dataclasses
@@ -290,6 +299,10 @@ def bench_psi():
     emit("psi_dh_60ids", us, "")
 
 
+# kernel rows time the Pallas interpreter on the host CPU, not a chip
+INTERPRET = "mode=interpret-cpu"
+
+
 def bench_kernels(quick: bool):
     import jax
     import jax.numpy as jnp
@@ -302,10 +315,10 @@ def bench_kernels(quick: bool):
 
     def run():
         return jax.block_until_ready(
-            ops.flash_attention(q, k, v, interpret=True))
+            ops.flash_attention(q, k, v))
     err = float(jnp.abs(run() - ref.attention_ref(q, k, v)).max())
     emit("kernel_flash_attention_256", _timeit(run, 3 if quick else 5),
-         f"max_err={err:.2e}")
+         f"max_err={err:.2e} {INTERPRET}")
 
     dt = jax.nn.softplus(jax.random.normal(ks[0], (1, 128, 64))) * 0.1
     bm = jax.random.normal(ks[1], (1, 128, 8))
@@ -315,10 +328,11 @@ def bench_kernels(quick: bool):
 
     def run2():
         return jax.block_until_ready(
-            ops.selective_scan(dt, bm, cm, u, a, interpret=True)[0])
+            ops.selective_scan(dt, bm, cm, u, a)[0])
     y2, _ = ref.selective_scan_ref(dt, bm, cm, u, a)
     err = float(jnp.abs(run2() - y2).max())
-    emit("kernel_selective_scan_128", _timeit(run2, 3), f"max_err={err:.2e}")
+    emit("kernel_selective_scan_128", _timeit(run2, 3),
+         f"max_err={err:.2e} {INTERPRET}")
 
     r_ = jax.random.normal(ks[0], (1, 2, 128, 32))
     w_ = jax.nn.sigmoid(jax.random.normal(ks[3], (1, 2, 128, 32))) * 0.5 + 0.4
@@ -326,29 +340,30 @@ def bench_kernels(quick: bool):
 
     def run3():
         return jax.block_until_ready(
-            ops.rwkv6_wkv(r_, r_, r_, w_, u_, interpret=True)[0])
+            ops.rwkv6_wkv(r_, r_, r_, w_, u_)[0])
     y3, _ = ref.rwkv6_ref(r_, r_, r_, w_, u_)
     err = float(jnp.abs(run3() - y3).max())
-    emit("kernel_rwkv6_wkv_128", _timeit(run3, 3), f"max_err={err:.2e}")
+    emit("kernel_rwkv6_wkv_128", _timeit(run3, 3),
+         f"max_err={err:.2e} {INTERPRET}")
 
     x = jax.random.normal(ks[0], (4, 128, 64))
     wm = jax.random.normal(ks[1], (4, 64, 128))
 
     def run4():
         return jax.block_until_ready(
-            ops.moe_gmm(x, wm, block_d=64, interpret=True))
+            ops.moe_gmm(x, wm, block_d=64))
     err = float(jnp.abs(run4() - ref.gmm_ref(x, wm)).max())
-    emit("kernel_moe_gmm_4x128", _timeit(run4, 3), f"max_err={err:.2e}")
+    emit("kernel_moe_gmm_4x128", _timeit(run4, 3),
+         f"max_err={err:.2e} {INTERPRET}")
 
     xq = jax.random.normal(ks[2], (512, 128)) * 2
 
     def run5():
-        return jax.block_until_ready(ops.quantize_int8(xq,
-                                                       interpret=True)[0])
+        return jax.block_until_ready(ops.quantize_int8(xq)[0])
     qk = run5()
     qr, _ = ref.quantize_int8_ref(xq)
     emit("kernel_quantize_int8_512", _timeit(run5, 3),
-         f"exact={bool((qk == qr).all())}")
+         f"exact={bool((qk == qr).all())} {INTERPRET}")
 
 
 def _seed_linreg_roles(master, members, cfg):
@@ -825,6 +840,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
+    print("# host-CPU bench: JAX_PLATFORMS=cpu, Pallas kernels in "
+          "interpret mode; no row is a device number")
     print("name,us_per_call,derived")
     bench_codec()
     bench_comm_modes()

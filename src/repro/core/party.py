@@ -395,6 +395,18 @@ class VFLJob:
                 self._threads.append(t)
                 t.start()
         elif mode in ("process", "socket_proc", "grpc_proc"):
+            import jax
+            backend = jax.default_backend()
+            if backend != "cpu":
+                # a chip belongs to one process at a time: spawned
+                # agents would fail to load it or wait on it forever
+                raise RuntimeError(
+                    f"mode={mode!r} starts one JAX process per agent, "
+                    f"but JAX here runs on {backend!r}, which only one "
+                    f"process can hold. Run the parties in this process "
+                    f"(mode='thread', 'socket' or 'grpc'), or set "
+                    f"JAX_PLATFORMS=cpu to run every agent on the host "
+                    f"CPU.")
             ctx = mp.get_context("spawn")
             if mode == "process":
                 from repro.comm.process import ProcessBus

@@ -812,6 +812,8 @@ def _cluster_agent_main(spec: ClusterSpec, role: str, log_path: str,
     comm = None
     try:
         from repro.core.party import Arbiter, PartyMaster, PartyMember
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
         comm = spec.make_communicator(role)
         status_q.put(("ready", role, os.getpid()))
         data = spec.build_data(role)

@@ -15,13 +15,10 @@ ICI_BW = 50e9                     # bytes/s per link
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """jax.make_mesh with Auto axis types, version-compat: AxisType
-    landed after jax 0.4.x; older versions default to Auto anyway."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axis types (sharding propagates from the
+    params and the constraints; no explicit-axis typing)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

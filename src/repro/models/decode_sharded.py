@@ -21,7 +21,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models import layers
-from repro.sharding.rules import MeshRules, shard_map_compat as _shard_map
+from repro.sharding.rules import MeshRules
 
 NEG_INF = -1e30
 
@@ -104,11 +104,12 @@ def sharded_decode_attention(cfg: ModelConfig, params, x, cache, index,
         o = o.reshape(o.shape[0], 1, h_eff, hd).astype(q.dtype)
         return o, k_shard, v_shard
 
-    out, k, v = _shard_map(
+    out, k, v = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec), P(bspec), P(bspec),
                   P(bspec, "model"), P(bspec, "model"), P()),
         out_specs=(P(bspec), P(bspec, "model"), P(bspec, "model")),
+        check_vma=False,
     )(q, k_new, v_new, cache["k"], cache["v"],
       jnp.asarray(index, jnp.int32))
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])

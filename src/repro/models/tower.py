@@ -274,28 +274,36 @@ def _use_pallas(kernel: str) -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _attention(q, k, v, kernel: str = "ref"):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _attention(q, k, v, kernel: str = "ref", shard=None):
     """Bidirectional multi-head attention, (b, h, s, dh) layout.
 
     Forward through ``kernels.ops.flash_attention`` (pallas) or the
     reference math; backward is always the reference VJP because
-    ``pallas_call`` is not reverse-differentiable.
+    ``pallas_call`` is not reverse-differentiable. ``shard`` is
+    ``(mesh, PartitionSpec)`` of q/k/v under a sharded tower, else None:
+    Mosaic kernels cannot be partitioned automatically, so a sharded
+    tower runs the kernel per shard under ``shard_map``.
     """
-    return _attention_fwd(q, k, v, kernel)[0]
+    return _attention_fwd(q, k, v, kernel, shard)[0]
 
 
-def _attention_fwd(q, k, v, kernel):
+def _attention_fwd(q, k, v, kernel, shard=None):
     if _use_pallas(kernel):
         from repro.kernels.ops import flash_attention
-        out = flash_attention(q, k, v, causal=False)
+        fa = partial(flash_attention, causal=False)
+        if shard is not None:
+            mesh, spec = shard
+            fa = jax.shard_map(fa, mesh=mesh, in_specs=(spec,) * 3,
+                               out_specs=spec, check_vma=False)
+        out = fa(q, k, v)
     else:
         from repro.kernels.ref import attention_ref
         out = attention_ref(q, k, v, causal=False)
     return out, (q, k, v)
 
 
-def _attention_bwd(kernel, res, g):
+def _attention_bwd(kernel, shard, res, g):
     from repro.kernels.ref import attention_ref
     q, k, v = res
     _, vjp = jax.vjp(
@@ -307,22 +315,30 @@ def _attention_bwd(kernel, res, g):
 _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(1,))
-def fake_quant(x, kernel: str = "ref"):
+@partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def fake_quant(x, kernel: str = "ref", shard=None):
     """Straight-through int8 fake-quantization (per-row symmetric).
 
     Forward quantizes+dequantizes on the wire codec's grid (pallas
     ``quantize_int8`` or the reference); backward is identity (STE).
+    ``shard`` is ``(mesh, PartitionSpec)`` of the flattened (rows, d)
+    activation under a sharded tower, else None (see :func:`_attention`).
     """
-    return _fq_fwd(x, kernel)[0]
+    return _fq_fwd(x, kernel, shard)[0]
 
 
-def _fq_fwd(x, kernel):
+def _fq_fwd(x, kernel, shard=None):
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     if _use_pallas(kernel):
-        from repro.kernels.ops import quantize_int8
-        q, scale = quantize_int8(x2, block_r=math.gcd(x2.shape[0], 256))
+        from repro.kernels.ops import quantize_int8 as quant
+        if shard is not None:
+            mesh, spec = shard
+            quant = jax.shard_map(
+                quant, mesh=mesh, in_specs=(spec,),
+                out_specs=(spec, jax.sharding.PartitionSpec(spec[0])),
+                check_vma=False)
+        q, scale = quant(x2)
     else:
         from repro.kernels.ref import quantize_int8_ref
         q, scale = quantize_int8_ref(x2)
@@ -330,7 +346,7 @@ def _fq_fwd(x, kernel):
     return y.reshape(shape), None
 
 
-def _fq_bwd(kernel, _, g):
+def _fq_bwd(kernel, shard, _, g):
     return (g,)
 
 
@@ -408,12 +424,19 @@ def apply(spec: TowerSpec, params: Sequence[Any], x,
     """Pure forward pass. ``rules`` (a ``MeshRules`` or None) is threaded
     explicitly — contextvars don't survive jit tracing boundaries."""
     for b, p in zip(spec.blocks, params):
-        x = _BLOCK_APPLY[b["kind"]](b, p, x)
+        x = _BLOCK_APPLY[b["kind"]](b, p, x, rules)
         if x.ndim == 3:
             x = _constrain(x, ("batch", None, None), rules)
         else:
             x = _constrain(x, ("batch", "mlp"), rules)
     return x
+
+
+def _shard(rules, logical, shape):
+    """(mesh, PartitionSpec) a kernel's shard_map runs over, or None."""
+    if rules is None:
+        return None
+    return rules.mesh, rules.act_spec(logical, shape)
 
 
 def _constrain(x, logical, rules):
@@ -424,7 +447,7 @@ def _constrain(x, logical, rules):
         x, jax.sharding.NamedSharding(rules.mesh, spec))
 
 
-def _apply_mlp(b, p, x):
+def _apply_mlp(b, p, x, rules=None):
     if x.ndim == 3:                   # sequence -> pooled features
         x = jnp.mean(x, axis=1)
     # exact legacy mlp_apply loop
@@ -436,7 +459,7 @@ def _apply_mlp(b, p, x):
     return x
 
 
-def _apply_embed(b, p, x):
+def _apply_embed(b, p, x, rules=None):
     t, c, nb = b["tokens"], b["chunk"], b["buckets"]
     pad = t * c - x.shape[-1]
     if pad:
@@ -455,7 +478,7 @@ def _rmsnorm(scale, x, eps: float = 1e-5):
     return x * jax.lax.rsqrt(var + eps) * scale
 
 
-def _apply_attn(b, p, x):
+def _apply_attn(b, p, x, rules=None):
     n, t, d = x.shape
     h = b["heads"]
     dh = d // h
@@ -464,7 +487,8 @@ def _apply_attn(b, p, x):
     q = (y @ p["wq"]).reshape(n, t, h, dh).transpose(0, 2, 1, 3)
     k = (y @ p["wk"]).reshape(n, t, h, dh).transpose(0, 2, 1, 3)
     v = (y @ p["wv"]).reshape(n, t, h, dh).transpose(0, 2, 1, 3)
-    o = _attention(q, k, v, b["kernel"])
+    o = _attention(q, k, v, b["kernel"],
+                   _shard(rules, ("batch", "heads", None, None), q.shape))
     o = o.transpose(0, 2, 1, 3).reshape(n, t, d) @ p["wo"]
     x = x + o
     y = _rmsnorm(p["ln2"], x)
@@ -472,8 +496,9 @@ def _apply_attn(b, p, x):
     return x + y
 
 
-def _apply_quant(b, p, x):
-    return fake_quant(x, b["kernel"])
+def _apply_quant(b, p, x, rules=None):
+    flat = (math.prod(x.shape[:-1]), x.shape[-1])
+    return fake_quant(x, b["kernel"], _shard(rules, ("batch", None), flat))
 
 
 _BLOCK_APPLY = {"mlp": _apply_mlp, "embed": _apply_embed,

@@ -22,18 +22,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: the top-level API (and its
-    check_vma kwarg) landed after 0.4.x; older jax ships it as
-    jax.experimental.shard_map with check_rep. Replication checking is
-    disabled either way (our psum-of-masks patterns confuse it)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
 # logical axis -> preferred mesh axes (tried in order, tuple = joint)
 PARAM_RULES: Dict[str, Optional[Tuple[str, ...]]] = {
     "embed": ("data",),          # FSDP shard of weight matrices

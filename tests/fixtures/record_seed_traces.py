@@ -5,8 +5,14 @@ The fixture pins the numerical behaviour of the protocol layer: the
 lifecycle API (core/protocols/driver.py) must reproduce these traces
 bit-for-bit (f64 paths) / to float32 tolerance (split-NN), which is how
 we know the refactor away from monolithic role functions changed zero
-arithmetic. The file checked in at tests/fixtures/seed_traces.json was
-generated against the pre-lifecycle seed code (commit ae0d7bc).
+arithmetic. The linreg and logreg_he traces in
+tests/fixtures/seed_traces.json were generated against the
+pre-lifecycle seed code (commit ae0d7bc); they use numpy float64 and
+re-record identically. The split_nn trace draws its init from
+jax.random, so it depends on JAX's PRNG implementation: it was
+re-recorded under JAX 0.9.0, where ``jax_threefry_partitionable``
+defaults to True (the ae0d7bc trace predates that default). The
+fixture's ``_provenance`` entry says the same.
 
 Configs use n divisible by batch_size so the traces are invariant to the
 drop_last default.
@@ -74,9 +80,17 @@ def main():
         "losses": [h["loss"] for h in res["master"]["history"]],
     }
 
+    import jax
+    traces["_provenance"] = (
+        "linreg/logreg_he: pre-lifecycle seed code (commit ae0d7bc), "
+        "numpy float64. split_nn: re-recorded with this script under "
+        f"jax {jax.__version__}, jax_threefry_partitionable="
+        f"{jax.config.jax_threefry_partitionable}, JAX_PLATFORMS=cpu.")
     OUT.write_text(json.dumps(traces, indent=1))
     print(f"wrote {OUT}")
     for k, v in traces.items():
+        if k.startswith("_"):
+            continue
         print(f"  {k}: {len(v['losses'])} steps, "
               f"loss {v['losses'][0]:.6f} -> {v['losses'][-1]:.6f}")
 

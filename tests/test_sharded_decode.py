@@ -28,7 +28,7 @@ def test_partial_softmax_decode_matches_baseline():
         cfg = get_config("glm4-9b").reduced()
         cfg = dataclasses.replace(cfg, n_kv_heads=2, n_heads=4, head_dim=32)
         from repro.launch.mesh import make_local_mesh
-        mesh = make_local_mesh(2, 4)    # AxisType-compat across jax versions
+        mesh = make_local_mesh(2, 4)
         rules = MeshRules(mesh)
         spec = T.model_spec(cfg)
         params = PRM.init_tree(spec, jax.random.key(0), jnp.float32)
