@@ -1,7 +1,7 @@
 """Transformer-tower split-NN under pipelining (DESIGN.md §12): the
 workload the tower factory exists for — member compute AND exchange
-both non-trivial, measured with the driver's per-step roofline
-accounting.
+both non-trivial, measured with the driver's per-step exchange
+account.
 
 Workload: one member with an embed + attn_block + mlp tower
 (`TowerSpec`, ~0.4 GFLOP forward per 512-row step) and a 128 KiB
@@ -11,7 +11,7 @@ OS process per agent (``socket_proc``), the link shaped to a
 per-step compute and wire time are the same order (each ≥ 25% of the
 step in the committed baseline). Depth 1 is lock-step; depth 2
 overlaps the member's forward with the in-flight exchange — the
-pipeline win the roofline split explains.
+pipeline win the exchange account explains.
 
 Methodology (the bench-discipline note in ROADMAP.md):
 
@@ -25,9 +25,9 @@ Methodology (the bench-discipline note in ROADMAP.md):
 
 Gated rows (benchmarks/check_regression.py, ``vfl_tower_`` prefix):
 ``vfl_tower_splitnn_d1`` and ``vfl_tower_splitnn_d2``; the d2 row's
-``derived`` carries the member's roofline split (compute_frac /
-wire_frac) and the d2-vs-d1 speedup. The ``vfl_tower_roofline_*``
-rows are informational (per-step compute seconds per role).
+``derived`` carries the member's exchange split (unblocked_frac /
+wire_frac) and the d2-vs-d1 speedup. The ``vfl_tower_exchange_*``
+rows are informational (per-step unblocked seconds per role).
 
 Standalone: PYTHONPATH=src python -m benchmarks.bench_tower [--quick]
 
@@ -102,7 +102,7 @@ def _bench_tower(emit, quick: bool) -> None:
 
     per_step = {1: float("inf"), 2: float("inf")}
     info: dict = {}
-    roof: dict = {}
+    exch: dict = {}
     for _ in range(2 if quick else 3):
         for depth in per_step:
             res = run_vfl(cfg, master, members, mode="socket_proc",
@@ -112,24 +112,24 @@ def _bench_tower(emit, quick: bool) -> None:
             if us < per_step[depth]:
                 per_step[depth] = us
                 info[depth] = f"steps={len(h)} loss={h[-1]['loss']:.4f}"
-                roof[depth] = {r: res[r]["roofline"]
+                exch[depth] = {r: res[r]["exchange"]
                                for r in ("master", "member0")}
     for depth, us in per_step.items():
-        m0 = roof[depth]["member0"]
+        m0 = exch[depth]["member0"]
         extra = "" if depth == 1 else \
             f" speedup_x{per_step[1] / max(us, 1e-9):.2f}"
         emit(f"vfl_tower_splitnn_d{depth}", us,
              f"{info[depth]} mode=socket_proc "
              f"wan={LATENCY_MS:.0f}ms/{BANDWIDTH_MBPS:.0f}Mbps "
-             f"member_compute_frac={m0['compute_frac']:.2f} "
+             f"member_unblocked_frac={m0['unblocked_frac']:.2f} "
              f"member_wire_frac={m0['wire_frac']:.2f}{extra}")
-    # informational: the per-role roofline split behind the d2 win
+    # informational: the per-role exchange split behind the d2 win
     for role in ("master", "member0"):
-        r = roof[2][role]
-        emit(f"vfl_tower_roofline_{role}",
-             r["compute_s_per_step"] * 1e6,
+        r = exch[2][role]
+        emit(f"vfl_tower_exchange_{role}",
+             r["unblocked_s_per_step"] * 1e6,
              f"d2 wall_us={r['wall_s_per_step'] * 1e6:.0f} "
-             f"compute_frac={r['compute_frac']:.2f} "
+             f"unblocked_frac={r['unblocked_frac']:.2f} "
              f"wire_frac={r['wire_frac']:.2f} "
              f"stall_frac={r['stall_frac']:.2f} "
              f"flops_per_step={r['model_flops_per_step']:.3g} "

@@ -32,7 +32,7 @@ SURFACE = [
                       "predict_master", "predict_member",
                       "predict_embed", "send_embed",
                       "evaluate_master", "finalize", "state_dict",
-                      "load_state_dict", "roofline_profile"]}),
+                      "load_state_dict", "cost_profile"]}),
     ("Configuration and data", "repro.core.protocols.base",
      ["VFLConfig", "MasterData", "MemberData", "register",
       "defense_noise"], {}),
@@ -41,8 +41,8 @@ SURFACE = [
       "mlp_tower", "legacy_dims_tower", "init", "apply",
       "logical_axes", "make_tower_rules", "shard_tower",
       "tower_flops"], {}),
-    ("Roofline accounting", "repro.launch.roofline",
-     ["step_account"], {}),
+    ("Exchange account", "repro.launch.exchange",
+     ["exchange_account"], {}),
     ("Adversarial harness", "repro.attacks",
      ["AttackHarness", "gradient_direction_attack", "cluster_attack",
       "probe_attack", "run_privacy_matrix"],

@@ -46,6 +46,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.comm import codec
 
 Payload = Dict[str, np.ndarray]
@@ -360,21 +361,23 @@ class _SendItem:
     None when encode is offloaded to the sender thread (the message's
     payload is already a snapshot, so late encode sees frozen bytes)."""
 
-    __slots__ = ("msg", "raw", "future", "t_enq", "phase")
+    __slots__ = ("msg", "raw", "future", "t_enq", "phase", "span")
 
     def __init__(self, msg: Message, raw: Optional[bytes],
-                 future: SendFuture, phase: str):
+                 future: SendFuture, phase: str, span: str):
         self.msg = msg
         self.raw = raw
         self.future = future
         self.t_enq = time.perf_counter()
         self.phase = phase
+        self.span = span
 
     def encode(self) -> bytes:
         if self.raw is None:
             m = self.msg
-            self.raw = codec.encode(
-                m.payload, {"sender": m.sender, "tag": m.tag, **m.meta})
+            with obs.span(self.span):
+                self.raw = codec.encode(
+                    m.payload, {"sender": m.sender, "tag": m.tag, **m.meta})
         return self.raw
 
 
@@ -390,6 +393,10 @@ class PartyCommunicator(abc.ABC):
         self.me = me
         self.world = list(world)
         self.stats = CommStats()
+        # span names (repro.obs), built once per party
+        self._sp_recv_wait = f"{me}.recv_wait"
+        self._sp_encode = f"{me}.encode"
+        self._sp_decode = f"{me}.decode"
         self.cfg = comm_cfg if comm_cfg is not None \
             else CommCfg(timeout=timeout)
         # CommCfg.timeout=None defers to the transport's constructor
@@ -602,8 +609,9 @@ class PartyCommunicator(abc.ABC):
         msg = Message(self.me, to, tag, payload, dict(meta or {}))
         if not encode:
             return msg, None
-        raw = codec.encode(payload, {"sender": self.me, "tag": tag,
-                                     **msg.meta})
+        with obs.span(self._sp_encode):
+            raw = codec.encode(payload, {"sender": self.me, "tag": tag,
+                                         **msg.meta})
         return msg, raw
 
     def _enqueue(self, msg: Message, raw: Optional[bytes],
@@ -615,7 +623,8 @@ class PartyCommunicator(abc.ABC):
             if raw is not None:
                 self.stats.record_send(msg.tag, len(raw),
                                        time.perf_counter() - t0)
-        self._sendq.put(_SendItem(msg, raw, fut, self.stats.phase))
+        self._sendq.put(_SendItem(msg, raw, fut, self.stats.phase,
+                                  self._sp_encode))
         return fut
 
     def isend(self, to: str, tag: str, payload: Payload,
@@ -719,9 +728,11 @@ class PartyCommunicator(abc.ABC):
              timeout: Optional[float] = None) -> Message:
         if timeout is None and frm in self._peer_timeouts:
             timeout = self._peer_timeouts[frm]
-        t0 = time.perf_counter()
-        msg = self._recv(frm, tag, timeout)
-        self.stats.record_recv(time.perf_counter() - t0)
+        with obs.span(self._sp_recv_wait):
+            t0 = time.perf_counter()
+            msg = self._recv(frm, tag, timeout)
+            wait = time.perf_counter() - t0
+        self.stats.record_recv(wait)
         return msg
 
     def recv_any(self, frm: str, tags: Sequence[str],
@@ -730,9 +741,11 @@ class PartyCommunicator(abc.ABC):
         of ``tags`` (stream-aware receives: data or a coalesced frame)."""
         if timeout is None and frm in self._peer_timeouts:
             timeout = self._peer_timeouts[frm]
-        t0 = time.perf_counter()
-        msg = self._recv_any(frm, tuple(tags), timeout)
-        self.stats.record_recv(time.perf_counter() - t0)
+        with obs.span(self._sp_recv_wait):
+            t0 = time.perf_counter()
+            msg = self._recv_any(frm, tuple(tags), timeout)
+            wait = time.perf_counter() - t0
+        self.stats.record_recv(wait)
         return msg
 
     def irecv(self, frm: str, tag: str) -> RecvFuture:

@@ -49,6 +49,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.comm import codec
 from repro.comm.base import Message
 from repro.comm.sock import (_MidFrameClose, _TcpCommunicator,
@@ -473,7 +474,8 @@ class GrpcCommunicator(_TcpCommunicator):
         if len(buf) - 5 != n:
             raise ConnectionError(
                 f"gRPC length prefix {n} != body {len(buf) - 5}")
-        payload, meta = codec.decode(buf[5:])
+        with obs.span(self._sp_decode):
+            payload, meta = codec.decode(buf[5:])
         sender = meta.pop("sender", sender)
         tag = meta.pop("tag")
         self._deliver(Message(sender, self.me, tag, payload, meta))

@@ -17,6 +17,7 @@ import queue
 import time
 from typing import Dict, Optional, Sequence, Tuple
 
+from repro import obs
 from repro.comm import codec
 from repro.comm.base import Message, PartyCommunicator
 
@@ -40,7 +41,8 @@ class _MailboxCommunicator(PartyCommunicator):
         raise NotImplementedError
 
     def _decode_one(self, raw: bytes) -> Message:
-        payload, meta = codec.decode(raw)
+        with obs.span(self._sp_decode):
+            payload, meta = codec.decode(raw)
         sender = meta.pop("sender")
         tag = meta.pop("tag")
         return Message(sender, self.me, tag, payload, meta)

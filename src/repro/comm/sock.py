@@ -31,6 +31,7 @@ import threading
 import time
 from typing import Dict, Optional, Sequence, Set, Tuple
 
+from repro import obs
 from repro.comm import codec
 from repro.comm.base import CommCfg, Message, PartyCommunicator
 
@@ -375,7 +376,8 @@ class SocketCommunicator(_TcpCommunicator):
                 (n,) = struct.unpack("<Q", _recv_exact(conn, 8))
                 mid_frame = True
                 raw = _recv_exact(conn, n)
-                payload, meta = codec.decode(raw)
+                with obs.span(self._sp_decode):
+                    payload, meta = codec.decode(raw)
                 sender = meta.pop("sender", sender)
                 tag = meta.pop("tag")
                 self._deliver(Message(sender, self.me, tag, payload,

@@ -46,6 +46,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.comm import schema
 from repro.comm.schema import Field, TypedChannel
 from repro.core.protocols.base import (VFLConfig, batch_bounds, batch_order,
@@ -303,14 +304,14 @@ class VFLProtocol:
     def load_state_dict(self, state: Dict[str, Any]) -> None:
         pass
 
-    # -- roofline hook -------------------------------------------------------
-    def roofline_profile(self) -> Optional[Dict[str, float]]:
+    # -- cost hook -----------------------------------------------------------
+    def cost_profile(self) -> Optional[Dict[str, float]]:
         """Analytic per-step cost of this role's model, or ``None``
         when the protocol doesn't account itself. Keys (all optional):
         ``flops_per_step`` (training FLOPs for one round),
         ``bytes_per_step`` (wire bytes this role exchanges per round),
-        ``params_bytes``. Merged into ``Driver.result()["roofline"]``
-        next to the measured compute/wire split (launch/roofline.py)."""
+        ``params_bytes``. Merged into ``Driver.result()["exchange"]``
+        next to the measured wall/wire split (launch/exchange.py)."""
         return None
 
 
@@ -538,32 +539,34 @@ class Driver:
         # member-side serve cache (cfg.serve_cache_rows); lazily built on
         # the first EVAL round a cache-capable protocol answers
         self._embed_cache: Optional[EmbedCache] = None
-        # per-step roofline accounting (launch/roofline.py): fit phases
+        # per-step exchange account (launch/exchange.py): fit phases
         # accumulate wall/steps plus CommStats counter deltas here, and
-        # result() resolves them into the compute-vs-wire split
+        # result() resolves them into the wall-vs-wire split
         self._fit_acc: Dict[str, float] = {"wall_s": 0.0, "steps": 0}
         # adversarial exchange capture (docs/privacy.md): installed on
         # the channel only when asked for — every other run keeps the
         # channel's ``capture`` at None and pays one is-None check
         if self.cfg.capture_exchanges:
             self.ch.capture = ExchangeCapture()
+        # span name (repro.obs), built once per party
+        self._sp_round = f"{self.role}.round"
 
-    _ROOF_COUNTERS = ("recv_wait_s", "send_s", "queued_s", "wire_s",
+    _EXCH_COUNTERS = ("recv_wait_s", "send_s", "queued_s", "wire_s",
                       "sent_bytes")
 
-    def _roof_snap(self) -> Dict[str, float]:
+    def _exch_snap(self) -> Dict[str, float]:
         s = self.ch.stats
-        return {k: float(getattr(s, k)) for k in self._ROOF_COUNTERS}
+        return {k: float(getattr(s, k)) for k in self._EXCH_COUNTERS}
 
-    def _roof_record(self, t0: float, snap: Dict[str, float],
+    def _exch_record(self, t0: float, snap: Dict[str, float],
                      step0: int) -> None:
         """Fold one fit phase's wall/steps/comm deltas into the
-        roofline accumulator (phases add up across refits)."""
+        exchange accumulator (phases add up across refits)."""
         acc = self._fit_acc
         acc["wall_s"] += time.perf_counter() - t0
         acc["steps"] += self.global_step - step0
-        now = self._roof_snap()
-        for k in self._ROOF_COUNTERS:
+        now = self._exch_snap()
+        for k in self._EXCH_COUNTERS:
             acc[k] = acc.get(k, 0.0) + now[k] - snap[k]
 
     # -- helpers -------------------------------------------------------------
@@ -638,10 +641,10 @@ class Driver:
         if getattr(self.ch, "capture", None) is not None:
             out["capture"] = self.ch.capture.as_dict()
         if self._fit_acc["steps"] > 0:
-            from repro.launch.roofline import step_account
-            out["roofline"] = step_account(
+            from repro.launch.exchange import exchange_account
+            out["exchange"] = exchange_account(
                 self._fit_acc["wall_s"], int(self._fit_acc["steps"]),
-                self._fit_acc, self.proto.roofline_profile())
+                self._fit_acc, self.proto.cost_profile())
         if self.role == "master":
             out["history"] = list(self.history)
             out["n_common"] = self.n
@@ -670,7 +673,7 @@ class Driver:
         """
         assert self.role == "master"
         t0 = time.perf_counter()
-        roof_snap, roof_step0 = self._roof_snap(), self.global_step
+        exch_snap, exch_step0 = self._exch_snap(), self.global_step
         cfg = self.cfg
         epochs = cfg.epochs if epochs is None else epochs
         # protocols without stage hooks run their members synchronously;
@@ -737,32 +740,35 @@ class Driver:
                     continue
                 break
             epoch, b, lo, hi = announced.popleft()
-            if epoch != cached_epoch:
-                perm = batch_order(self.n, cfg, epoch)
-                cached_epoch = epoch
-            loss = self.proto.on_batch_master(perm[lo:hi],
-                                              self.global_step)
-            if self.global_step % cfg.record_every == 0:
-                # wall_s (since fit start) lets offline analysis split
-                # steady-state step time from jit/pipeline warmup
-                self.history.append({"step": self.global_step,
-                                     "epoch": epoch, "loss": loss,
-                                     "wall_s": round(
-                                         time.perf_counter() - t0, 6)})
-            self.global_step += 1
-            self._pos = (epoch, b + 1)
-            self._invoke("on_batch_end", self.global_step - 1, epoch,
-                         loss)
-            if b == last_b and not self._stop:
-                self._pos = (epoch + 1, 0)
-                self._invoke("on_epoch_end", epoch)
+            with obs.span(self._sp_round, step=self.global_step):
+                if epoch != cached_epoch:
+                    perm = batch_order(self.n, cfg, epoch)
+                    cached_epoch = epoch
+                loss = self.proto.on_batch_master(perm[lo:hi],
+                                                  self.global_step)
+                if self.global_step % cfg.record_every == 0:
+                    # wall_s (since fit start) lets offline analysis
+                    # split steady-state step time from jit/pipeline
+                    # warmup
+                    self.history.append({"step": self.global_step,
+                                         "epoch": epoch, "loss": loss,
+                                         "wall_s": round(
+                                             time.perf_counter() - t0,
+                                             6)})
+                self.global_step += 1
+                self._pos = (epoch, b + 1)
+                self._invoke("on_batch_end", self.global_step - 1,
+                             epoch, loss)
+                if b == last_b and not self._stop:
+                    self._pos = (epoch + 1, 0)
+                    self._invoke("on_epoch_end", epoch)
         self.ch.round_deadline = None     # disarm: predict waits fully
         self.ch._drain_stale()            # consume late straggler msgs
         self.ch.broadcast("ctrl/step", _step_payload(OP_END, -1, 0, 0),
                           targets=self._others)
         self.stopped = self._stop
         self._invoke("on_fit_end")
-        self._roof_record(t0, roof_snap, roof_step0)
+        self._exch_record(t0, exch_snap, exch_step0)
         self._timed("fit", t0)
         out = {"history": list(self.history), "n_common": self.n,
                "stopped": self.stopped,
@@ -926,10 +932,10 @@ class Driver:
                     # bottom model is about to change
                     self._embed_cache.invalidate()
                 self._invoke("on_fit_start")
-                roof_snap, roof_step0 = self._roof_snap(), \
+                exch_snap, exch_step0 = self._exch_snap(), \
                     self.global_step
                 self._follow_steps()
-                self._roof_record(t0, roof_snap, roof_step0)
+                self._exch_record(t0, exch_snap, exch_step0)
                 self._invoke("on_fit_end")
                 self._timed("fit", t0)
             elif op == PHASE_PREDICT:
@@ -963,9 +969,9 @@ class Driver:
         t0 = time.perf_counter()
         self.ch.stats.phase = "fit"
         self._invoke("on_fit_start")
-        roof_snap, roof_step0 = self._roof_snap(), self.global_step
+        exch_snap, exch_step0 = self._exch_snap(), self.global_step
         self._follow_steps()
-        self._roof_record(t0, roof_snap, roof_step0)
+        self._exch_record(t0, exch_snap, exch_step0)
         self._invoke("on_fit_end")
         self._timed("fit", t0)
         return self.follow(idle_timeout)

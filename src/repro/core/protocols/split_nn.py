@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.comm import schema
 from repro.comm.schema import Field
 from repro.core.protocols import base
@@ -145,6 +146,10 @@ class SplitNNProtocol(VFLProtocol):
         cfg, d = self.cfg, self.data
         self.lr = jnp.float32(cfg.lr)
         key = jax.random.key(cfg.seed)
+        # span names (repro.obs), built once per party
+        r = self.role
+        self._sp_h2d, self._sp_gather = f"{r}.h2d", f"{r}.gather"
+        self._sp_step, self._sp_d2h = f"{r}.step", f"{r}.d2h"
         if self.is_master:
             self.y = jnp.asarray(
                 base._select(d.ids, self.order, d.y), jnp.float32)
@@ -191,9 +196,9 @@ class SplitNNProtocol(VFLProtocol):
                 self.masker = PairwiseMasker(self.ch.comm, self.role,
                                              self.ch.members)
 
-    def roofline_profile(self) -> Dict[str, float]:
-        """Analytic per-step cost for the roofline accounting
-        (launch/roofline.py): training FLOPs ~= 3x the forward pass
+    def cost_profile(self) -> Dict[str, float]:
+        """Analytic per-step cost for the exchange account
+        (launch/exchange.py): training FLOPs ~= 3x the forward pass
         (fwd + input/weight VJPs), wire bytes = the float32 u/du
         exchange this role sees each round."""
         cfg = self.cfg
@@ -215,26 +220,34 @@ class SplitNNProtocol(VFLProtocol):
     def on_batch_master(self, rows, step) -> float:
         ch = self.ch
         msgs = ch.gather(ch.members, "splitnn/u")
-        # fit_rows: a stale substitution (down/straggling peer) may
-        # carry a different tail-batch row count than this round
-        u_members = tuple(
-            jnp.asarray(base.fit_rows(m.tensor("u"), len(rows)),
-                        jnp.float32) for m in msgs)
-        loss, self.top, self.bottom, g_u = self._step(
-            self.top, self.bottom, u_members, self.x[rows], self.y[rows],
-            self.lr)
+        with obs.span(self._sp_h2d, step=step):
+            # fit_rows: a stale substitution (down/straggling peer) may
+            # carry a different tail-batch row count than this round
+            u_members = tuple(
+                jnp.asarray(base.fit_rows(m.tensor("u"), len(rows)),
+                            jnp.float32) for m in msgs)
+        with obs.span(self._sp_gather, step=step):
+            xb, yb = self.x[rows], self.y[rows]
+        with obs.span(self._sp_step, step=step):
+            loss, self.top, self.bottom, g_u = self._step(
+                self.top, self.bottom, u_members, xb, yb, self.lr)
         for mname, du in zip(ch.members, g_u):
+            with obs.span(self._sp_d2h, step=step):
+                du = np.asarray(du)
             # isend: the per-member gradient writes overlap each other
             # and the next round's activation gather
-            ch.isend(mname, "splitnn/du", {"du": np.asarray(du)})
-        return float(loss)
+            ch.isend(mname, "splitnn/du", {"du": du})
+        with obs.span(self._sp_d2h, step=step):
+            return float(loss)
 
     def member_stage_send(self, rows, step):
         """Bottom forward + activation isend; the batch slice is the ctx
         the deferred backward stage reuses (its VJP must see the inputs
         this forward actually saw)."""
-        xb = self.x[rows]
-        u = self._fwd(self.params, xb)
+        with obs.span(self._sp_gather, step=step):
+            xb = self.x[rows]
+        with obs.span(self._sp_step, step=step):
+            u = self._fwd(self.params, xb)
         if self.cfg.noise_sigma > 0:
             # noising defense (docs/privacy.md): the member perturbs
             # its outgoing embedding before any masking, so neither the
@@ -247,20 +260,32 @@ class SplitNNProtocol(VFLProtocol):
         if self.masker is not None:
             u = jnp.asarray(np.asarray(u)
                             + self.masker.mask(step, np.asarray(u).shape))
-        self.ch.isend("master", "splitnn/u", {"u": np.asarray(u)})
+        with obs.span(self._sp_d2h, step=step):
+            u = np.asarray(u)
+        self.ch.isend("master", "splitnn/u", {"u": u})
         return xb
 
     def member_stage_recv(self, rows, step, xb) -> None:
-        du = jnp.asarray(
-            self.ch.recv("master", "splitnn/du").tensor("du"), jnp.float32)
-        self.params = self._bwd(self.params, xb, du, self.lr)
+        du = self.ch.recv("master", "splitnn/du").tensor("du")
+        with obs.span(self._sp_h2d, step=step):
+            du = jnp.asarray(du, jnp.float32)
+        with obs.span(self._sp_step, step=step):
+            self.params = self._bwd(self.params, xb, du, self.lr)
 
     # -- predict/serve -------------------------------------------------------
     def predict_master(self, rows) -> np.ndarray:
-        u = self._fwd(self.bottom, self.x[rows])
+        with obs.span(self._sp_gather):
+            xb = self.x[rows]
+        with obs.span(self._sp_step):
+            u = self._fwd(self.bottom, xb)
         for msg in self.ch.gather(self.ch.members, "splitnn/pred_u"):
-            u = u + jnp.asarray(msg.tensor("u"), jnp.float32)
-        return np.asarray(self._top_fwd(self.top, u))
+            with obs.span(self._sp_h2d):
+                um = jnp.asarray(msg.tensor("u"), jnp.float32)
+            u = u + um
+        with obs.span(self._sp_step):
+            scores = self._top_fwd(self.top, u)
+        with obs.span(self._sp_d2h):
+            return np.asarray(scores)
 
     def predict_member(self, rows) -> None:
         self.send_embed(self.predict_embed(rows), rows)
@@ -268,7 +293,12 @@ class SplitNNProtocol(VFLProtocol):
     def predict_embed(self, rows) -> np.ndarray:
         # pure bottom-model forward: cacheable per row (no masking —
         # masks are per-query and applied in send_embed)
-        return np.asarray(self._fwd(self.params, self.x[rows]))
+        with obs.span(self._sp_gather):
+            xb = self.x[rows]
+        with obs.span(self._sp_step):
+            u = self._fwd(self.params, xb)
+        with obs.span(self._sp_d2h):
+            return np.asarray(u)
 
     def send_embed(self, u, rows) -> None:
         if self.masker is not None:

@@ -872,9 +872,9 @@ def _cluster_agent_main(spec: ClusterSpec, role: str, log_path: str,
                     summary["serve"] = _serve_phase(spec, agent)
             res = agent.shutdown()
             summary["comm"] = _json_safe(res.get("comm"))
-            if res.get("roofline"):
-                # per-step compute-vs-wire split (launch/roofline.py)
-                summary["roofline"] = _json_safe(res["roofline"])
+            if res.get("exchange"):
+                # per-step wall-vs-wire split (launch/exchange.py)
+                summary["exchange"] = _json_safe(res["exchange"])
             status_q.put(("ok", role, summary))
         else:
             agent = PartyMember(comm, spec.cfg, callbacks=callbacks,
@@ -884,8 +884,8 @@ def _cluster_agent_main(spec: ClusterSpec, role: str, log_path: str,
             res = agent.serve(data, rejoin=rejoin) \
                 if role.startswith("member") else agent.serve()
             out = {"comm": _json_safe(res.get("comm"))}
-            if res.get("roofline"):
-                out["roofline"] = _json_safe(res["roofline"])
+            if res.get("exchange"):
+                out["exchange"] = _json_safe(res["exchange"])
             status_q.put(("ok", role, out))
     except BaseException:
         tb = traceback.format_exc()

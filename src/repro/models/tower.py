@@ -39,8 +39,8 @@ Blocks are written either as dicts or as compact strings
 validates the chain; ``init``/``apply`` are the pure param functions;
 ``logical_axes``/``shard_tower``/``make_tower_rules`` place a large
 tower on the local mesh (``launch/mesh.py`` + ``sharding/rules.py``);
-``tower_flops`` is the analytic forward cost used by the roofline
-accounting (``launch/roofline.py``).
+``tower_flops`` is the analytic forward cost used by the exchange
+account (``launch/exchange.py``) and the benchmark's references.
 """
 from __future__ import annotations
 
@@ -565,7 +565,7 @@ def shard_tower(params: Sequence[Any], spec: TowerSpec, rules):
 
 
 # ---------------------------------------------------------------------------
-# analytic cost (roofline)
+# analytic cost
 # ---------------------------------------------------------------------------
 
 

@@ -42,10 +42,18 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.comm import codec
 
 __all__ = ["ServeCfg", "ServeStats", "AdmissionError", "FederatedServer",
            "ServeFrontend", "ServeClient"]
+
+
+# batcher spans (repro.obs; docs/serving.md "Tracing")
+_SP_TAKE = "serve.batcher.take"
+_SP_IDLE = "serve.batcher.idle"
+_SP_HOLD = "serve.batcher.hold"
+_SP_FINISH = "serve.batcher.finish"
 
 
 class AdmissionError(RuntimeError):
@@ -241,7 +249,8 @@ class FederatedServer:
         cfg = self.cfg
         with self._cv:
             while not self._queue and not self._stopping:
-                self._cv.wait(0.05)
+                with obs.span(_SP_IDLE):
+                    self._cv.wait(0.05)
             if not self._queue:
                 return []
             batch = [self._queue.popleft()]
@@ -258,7 +267,8 @@ class FederatedServer:
                 remaining = deadline - time.perf_counter()
                 if remaining <= 0 or self._stopping:
                     break
-                self._cv.wait(remaining)
+                with obs.span(_SP_HOLD):
+                    self._cv.wait(remaining)
             self._queued_rows -= nrows
         now = time.perf_counter()
         for p in batch:
@@ -267,7 +277,8 @@ class FederatedServer:
 
     def _batch_loop(self) -> None:
         while True:
-            batch = self._take_batch()
+            with obs.span(_SP_TAKE):
+                batch = self._take_batch()
             if not batch:
                 return
             rows = np.concatenate([p.rows for p in batch])
@@ -289,14 +300,15 @@ class FederatedServer:
                     p.done.set()
                 self._queue.clear()
                 return
-            t_done = time.perf_counter()
-            lo = 0
-            for p in batch:
-                p.scores = scores[lo:lo + len(p.rows)]
-                lo += len(p.rows)
-                p.t_done = t_done
-                self.stats.record(p)
-                p.done.set()
+            with obs.span(_SP_FINISH):
+                t_done = time.perf_counter()
+                lo = 0
+                for p in batch:
+                    p.scores = scores[lo:lo + len(p.rows)]
+                    lo += len(p.rows)
+                    p.t_done = t_done
+                    self.stats.record(p)
+                    p.done.set()
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Composable tower factory (DESIGN.md §12): spec parsing/validation,
 bit-identity of the default MLP path with the recorded seed traces,
 transformer-tower convergence under pipelining, pallas-vs-reference
-kernel parity, mesh sharding, roofline accounting, and the per-link
+kernel parity, mesh sharding, the exchange account, and the per-link
 ``[comm.a.b]`` CommCfg overrides that ride the same PR."""
 import dataclasses
 import json
@@ -19,7 +19,7 @@ from repro.core.protocols.base import VFLConfig
 from repro.core.protocols.split_nn import (SplitNNProtocol, bottom_spec,
                                            mlp_init, top_spec)
 from repro.data.vertical import vertical_partition
-from repro.launch.roofline import step_account
+from repro.launch.exchange import exchange_account
 from repro.models import tower as twr
 
 TRACES = json.loads(
@@ -204,10 +204,10 @@ def test_transformer_tower_converges_at_depth2():
     res = run_vfl(cfg, master, members, mode="thread")
     losses = [h["loss"] for h in res["master"]["history"]]
     assert losses[-1] < losses[0]
-    roof = res["master"]["roofline"]
-    assert roof["steps"] == len(losses)
-    assert roof["model_flops_per_step"] > 0
-    assert res["member0"]["roofline"]["model_bytes_per_step"] > 0
+    acc = res["master"]["exchange"]
+    assert acc["steps"] == len(losses)
+    assert acc["model_flops_per_step"] > 0
+    assert res["member0"]["exchange"]["model_bytes_per_step"] > 0
 
 
 def test_tower_depths_agree_on_final_loss():
@@ -307,22 +307,22 @@ def test_make_tower_rules_guards_device_count():
 
 
 # ---------------------------------------------------------------------------
-# roofline accounting
+# exchange account
 # ---------------------------------------------------------------------------
 
 
 def test_step_account_splits_wall():
-    acc = step_account(
+    acc = exchange_account(
         10.0, 100,
         {"recv_wait_s": 2.0, "send_s": 1.0, "queued_s": 0.5,
          "wire_s": 1.5, "sent_bytes": 1000.0},
         profile={"flops_per_step": 2e6, "bytes_per_step": 1e3,
                  "params_bytes": 4096})
     assert acc["steps"] == 100
-    assert acc["compute_s_per_step"] == pytest.approx(0.07)
+    assert acc["unblocked_s_per_step"] == pytest.approx(0.07)
     assert acc["wire_s_per_step"] == pytest.approx(0.03)
     assert acc["stall_s_per_step"] == pytest.approx(0.02)
-    assert acc["dominant"] == "compute"
+    assert acc["dominant"] == "unblocked"
     assert acc["exchange_intensity"] == pytest.approx(2000.0)
     assert acc["params_bytes"] == 4096
 
@@ -331,11 +331,11 @@ def test_driver_result_carries_roofline():
     cfg, master, members = _splitnn_case(epochs=1)
     res = run_vfl(cfg, master, members, mode="thread")
     for role in ("master", "member0"):
-        roof = res[role]["roofline"]
-        assert roof["steps"] > 0
-        assert roof["wall_s_per_step"] > 0
-        assert 0.0 <= roof["stall_frac"]
-        assert roof["model_flops_per_step"] > 0
+        acc = res[role]["exchange"]
+        assert acc["steps"] > 0
+        assert acc["wall_s_per_step"] > 0
+        assert 0.0 <= acc["stall_frac"]
+        assert acc["model_flops_per_step"] > 0
 
 
 def test_roofline_profile_counts_tower_flops():
@@ -346,7 +346,7 @@ def test_roofline_profile_counts_tower_flops():
     proto.cfg, proto.role = cfg, "member0"
     proto._spec = spec
     proto.params = twr.init(spec, jax.random.PRNGKey(0))
-    prof = proto.roofline_profile()
+    prof = proto.cost_profile()
     assert prof["flops_per_step"] == pytest.approx(3.0 * per_fwd)
     assert prof["bytes_per_step"] == pytest.approx(
         2.0 * cfg.batch_size * cfg.embedding_dim * 4)
@@ -511,7 +511,7 @@ def test_comm_timeout_overrides_edge_pinned_timeouts():
 def test_vfljob_honors_comm_cfgs():
     """VFLJob plumbs per-role resolved CommCfgs (what from_spec builds
     from [comm.a.b] edges) down to each agent's communicator; the run
-    still trains and carries the roofline account."""
+    still trains and carries the exchange account."""
     from repro.comm.base import CommCfg, LinkSpec
     from repro.core.party import VFLJob
     cfg, master, members = _splitnn_case(epochs=1)
@@ -526,6 +526,6 @@ def test_vfljob_honors_comm_cfgs():
         assert np.isfinite(fit["history"][-1]["loss"])
     finally:
         res = job.shutdown()
-    assert res["master"]["roofline"]["steps"] > 0
+    assert res["master"]["exchange"]["steps"] > 0
     # the shaped link actually metered wire time
     assert res["master"]["comm"]["wire_s"] > 0
