@@ -27,6 +27,7 @@ top model — nobody ever holds another silo's features or parameters.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Dict, List, Tuple
 
@@ -100,6 +101,46 @@ def top_spec(cfg, items: int) -> twr.TowerSpec:
                          final_act=False)
 
 
+LANES = 128
+
+
+@functools.partial(jax.tree_util.register_dataclass, data_fields=["data"],
+                   meta_fields=["width"])
+@dataclasses.dataclass(frozen=True)
+class _Silo:
+    """A party's silo on the device, its feature width zero-padded to
+    whole 128-lane tiles; ``width`` is the silo's own.
+
+    A TPU keeps an array in whichever tiled layout pads it least. For a
+    float32 silo whose width is not a multiple of 128 that can be the
+    column-major one, and then every row gather first relays out the
+    whole silo (Table 1's 1,345-wide master silo: 615 MB, ~1.9 ms a round
+    on a v5e). Padded, the layout the chip picks is the row-major one a
+    row gather reads. The layout is chosen by the shape, not committed
+    to the array, since a jitted program loaded from JAX's persistent
+    compilation cache does not keep a committed input layout."""
+    data: jax.Array
+    width: int
+
+    @classmethod
+    def put(cls, arr) -> "_Silo":
+        x = jnp.asarray(arr, jnp.float32)
+        width = x.shape[1]
+        return cls(jnp.pad(x, ((0, 0), (0, -width % LANES))), width)
+
+
+@jax.jit
+def _take_rows(silos, rows):
+    return tuple(s.data[rows, :s.width] for s in silos)
+
+
+def _take(silos, rows) -> Tuple[jax.Array, ...]:
+    """One party's row take for a round: the rows, uploaded once as
+    int32, of every silo in one dispatch. A gather is exact, so each
+    value is the one ``silo[rows]`` gives."""
+    return _take_rows(silos, np.asarray(rows, np.int32))
+
+
 def _make_master_step(bspec: twr.TowerSpec, tspec: twr.TowerSpec):
     @jax.jit
     def step(top_params, bottom_params, u_members, x_m, y, lr):
@@ -151,12 +192,10 @@ class SplitNNProtocol(VFLProtocol):
         self._sp_h2d, self._sp_gather = f"{r}.h2d", f"{r}.gather"
         self._sp_step, self._sp_d2h = f"{r}.step", f"{r}.d2h"
         if self.is_master:
-            self.y = jnp.asarray(
-                base._select(d.ids, self.order, d.y), jnp.float32)
-            self.x = jnp.asarray(
-                base._select(d.ids, self.order, d.x), jnp.float32)
-            items = self.y.shape[1]
-            self._bspec = bottom_spec(cfg, self.x.shape[1])
+            self.y = _Silo.put(base._select(d.ids, self.order, d.y))
+            self.x = _Silo.put(base._select(d.ids, self.order, d.x))
+            items = self.y.width
+            self._bspec = bottom_spec(cfg, self.x.width)
             self._tspec = top_spec(cfg, items)
             self.bottom = twr.init(self._bspec,
                                    jax.random.fold_in(key, 0))
@@ -168,11 +207,10 @@ class SplitNNProtocol(VFLProtocol):
             self._top_fwd = jax.jit(functools.partial(twr.apply,
                                                       self._tspec))
         else:
-            self.x = jnp.asarray(
-                base._select(d.ids, self.order, d.x), jnp.float32)
+            self.x = _Silo.put(base._select(d.ids, self.order, d.x))
             # member index determines its init stream (from its id)
             midx = int(self.role.replace("member", "")) + 2
-            self._spec = bottom_spec(cfg, self.x.shape[1])
+            self._spec = bottom_spec(cfg, self.x.width)
             self.params = twr.init(self._spec,
                                    jax.random.fold_in(key, midx))
             # model-parallel placement of a large member tower over the
@@ -227,7 +265,7 @@ class SplitNNProtocol(VFLProtocol):
                 jnp.asarray(base.fit_rows(m.tensor("u"), len(rows)),
                             jnp.float32) for m in msgs)
         with obs.span(self._sp_gather, step=step):
-            xb, yb = self.x[rows], self.y[rows]
+            xb, yb = _take((self.x, self.y), rows)
         with obs.span(self._sp_step, step=step):
             loss, self.top, self.bottom, g_u = self._step(
                 self.top, self.bottom, u_members, xb, yb, self.lr)
@@ -245,7 +283,7 @@ class SplitNNProtocol(VFLProtocol):
         the deferred backward stage reuses (its VJP must see the inputs
         this forward actually saw)."""
         with obs.span(self._sp_gather, step=step):
-            xb = self.x[rows]
+            (xb,) = _take((self.x,), rows)
         with obs.span(self._sp_step, step=step):
             u = self._fwd(self.params, xb)
         if self.cfg.noise_sigma > 0:
@@ -275,7 +313,7 @@ class SplitNNProtocol(VFLProtocol):
     # -- predict/serve -------------------------------------------------------
     def predict_master(self, rows) -> np.ndarray:
         with obs.span(self._sp_gather):
-            xb = self.x[rows]
+            (xb,) = _take((self.x,), rows)
         with obs.span(self._sp_step):
             u = self._fwd(self.bottom, xb)
         for msg in self.ch.gather(self.ch.members, "splitnn/pred_u"):
@@ -294,7 +332,7 @@ class SplitNNProtocol(VFLProtocol):
         # pure bottom-model forward: cacheable per row (no masking —
         # masks are per-query and applied in send_embed)
         with obs.span(self._sp_gather):
-            xb = self.x[rows]
+            (xb,) = _take((self.x,), rows)
         with obs.span(self._sp_step):
             u = self._fwd(self.params, xb)
         with obs.span(self._sp_d2h):
@@ -311,8 +349,8 @@ class SplitNNProtocol(VFLProtocol):
 
     def evaluate_master(self, scores, rows) -> Dict[str, float]:
         from repro.train.evals import recsys_report
-        return recsys_report(np.asarray(scores),
-                             np.asarray(self.y[rows]), k=5)
+        (yb,) = _take((self.y,), rows)
+        return recsys_report(np.asarray(scores), np.asarray(yb), k=5)
 
     def finalize(self) -> Dict:
         if self.is_master:
