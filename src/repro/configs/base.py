@@ -27,6 +27,12 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_aux_weight: float = 1e-2
     router_z_weight: float = 1e-3
+    # gating, as the HF config names it: the top-k scores renormalized
+    # to sum to one (True) or left as the softmax gives them (DeepSeek-V2),
+    # then scaled by routed_scaling_factor
+    norm_topk_prob: bool = True
+    scoring_func: str = "softmax"
+    routed_scaling_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -38,6 +44,23 @@ class MLAConfig:
     rope_head_dim: int
     nope_head_dim: int
     v_head_dim: int
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (HF ``rope_scaling`` of ``type: yarn``, as
+    DeepSeek-V2 uses it): the low frequencies are stretched by
+    ``factor`` with a linear ramp between the ``beta_fast`` and
+    ``beta_slow`` rotation counts over ``original_max_position``, and the
+    attention logits are scaled by ``mscale(factor, mscale_all_dim)**2``
+    (see ``layers.rope_frequencies``)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -110,6 +133,7 @@ class ModelConfig:
     qk_norm: bool = False
     rope: bool = True
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnConfig] = None
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     mamba: Optional[MambaConfig] = None

@@ -114,6 +114,9 @@ class VFLConfig:
     # member; this configures the top model over the summed embeddings.
     # Empty = the legacy MLP from ``hidden``.
     top_tower: Tuple[str, ...] = ()
+    # the master's own bottom tower where it differs from the members'
+    # (a token-id member tower over a dense master silo). Empty = ``tower``.
+    master_tower: Tuple[str, ...] = ()
     # model-parallel sharding of the member tower over N local devices
     # (launch/mesh.py x sharding/rules.py). 1 = unsharded single-device
     # params (the default; no mesh is ever constructed).
@@ -130,7 +133,9 @@ class MasterData:
 @dataclass
 class MemberData:
     ids: List[str]
-    x: np.ndarray                  # (n, d_p)
+    # (n, d_p) features, or (n, seq) integer token ids for a tower that
+    # starts with a ``tokens`` block (kept int32, never cast to float)
+    x: np.ndarray
 
 
 def _select(ids: Sequence[str], order: Sequence[str], arr: np.ndarray

@@ -84,10 +84,12 @@ def _bce(logits, y):
                     + jnp.log1p(jnp.exp(-jnp.abs(logits))))
 
 
-def bottom_spec(cfg, in_dim: int) -> twr.TowerSpec:
-    """Resolve the bottom-model tower for one party's feature width."""
-    if cfg.tower:
-        return twr.resolve(tuple(cfg.tower), in_dim, cfg.embedding_dim)
+def bottom_spec(cfg, in_dim: int, master: bool = False) -> twr.TowerSpec:
+    """Resolve the bottom-model tower for one party's feature width (the
+    master's from ``cfg.master_tower`` where that is set)."""
+    blocks = (cfg.master_tower or cfg.tower) if master else cfg.tower
+    if blocks:
+        return twr.resolve(tuple(blocks), in_dim, cfg.embedding_dim)
     return twr.mlp_tower(in_dim, cfg.hidden, cfg.embedding_dim,
                          final_act=True)
 
@@ -109,7 +111,8 @@ LANES = 128
 @dataclasses.dataclass(frozen=True)
 class _Silo:
     """A party's silo on the device, its feature width zero-padded to
-    whole 128-lane tiles; ``width`` is the silo's own.
+    whole 128-lane tiles; ``width`` is the silo's own. Integer silos
+    (token ids) are kept as int32, everything else as float32.
 
     A TPU keeps an array in whichever tiled layout pads it least. For a
     float32 silo whose width is not a multiple of 128 that can be the
@@ -124,7 +127,9 @@ class _Silo:
 
     @classmethod
     def put(cls, arr) -> "_Silo":
-        x = jnp.asarray(arr, jnp.float32)
+        dtype = arr.dtype if hasattr(arr, "dtype") else np.asarray(arr).dtype
+        ints = np.issubdtype(dtype, np.integer)
+        x = jnp.asarray(arr, jnp.int32 if ints else jnp.float32)
         width = x.shape[1]
         return cls(jnp.pad(x, ((0, 0), (0, -width % LANES))), width)
 
@@ -178,6 +183,37 @@ def _make_member_fns(spec: twr.TowerSpec, rules):
     return fwd, bwd
 
 
+def _make_routed_member_fns(spec: twr.TowerSpec, rules):
+    """A member tower with ``moe`` blocks: the forward also gives each
+    block's expert choices and adds its rows per held expert to the
+    running counts ``stats`` on the device; the backward routes by the
+    choices its forward made."""
+    @jax.jit
+    def fwd(params, x, stats):
+        u, aux = twr.apply_routed(spec, params, x, rules=rules)
+        return u, aux["routes"], {
+            "rows": tuple(a + b for a, b in zip(stats["rows"],
+                                                aux["rows"])),
+            "dropped": stats["dropped"] + aux["dropped"]}
+
+    @jax.jit
+    def bwd(params, x, du, lr, routes):
+        _, vjp = jax.vjp(lambda p: twr.apply_routed(
+            spec, p, x, routes=routes, rules=rules)[0], params)
+        (g,) = vjp(du)
+        return jax.tree.map(lambda p, gg: p - lr * gg, params, g)
+
+    return fwd, bwd
+
+
+def route_stats(spec: twr.TowerSpec) -> Dict[str, object]:
+    """Zero counts for :func:`_make_routed_member_fns`: rows routed to
+    each held expert of each ``moe`` block, and rows dropped."""
+    return {"rows": tuple(jnp.zeros((b["held"],), jnp.int32)
+                          for b in twr.routed_blocks(spec)),
+            "dropped": jnp.zeros((), jnp.int32)}
+
+
 @base.register
 class SplitNNProtocol(VFLProtocol):
     name = "split_nn"
@@ -195,7 +231,7 @@ class SplitNNProtocol(VFLProtocol):
             self.y = _Silo.put(base._select(d.ids, self.order, d.y))
             self.x = _Silo.put(base._select(d.ids, self.order, d.x))
             items = self.y.width
-            self._bspec = bottom_spec(cfg, self.x.width)
+            self._bspec = bottom_spec(cfg, self.x.width, master=True)
             self._tspec = top_spec(cfg, items)
             self.bottom = twr.init(self._bspec,
                                    jax.random.fold_in(key, 0))
@@ -218,8 +254,21 @@ class SplitNNProtocol(VFLProtocol):
             self._rules = twr.make_tower_rules(cfg.tower_shard)
             self.params = twr.shard_tower(self.params, self._spec,
                                           self._rules)
-            self._fwd, self._bwd = _make_member_fns(self._spec,
-                                                    self._rules)
+            # a tower with moe blocks keeps its expert choices of the
+            # last forward (``routes``, the backward's) and its routed
+            # rows per held expert (``route_stats``), on the device
+            self._routed = bool(twr.routed_blocks(self._spec))
+            if self._routed:
+                self._fwd, self._bwd = _make_routed_member_fns(
+                    self._spec, self._rules)
+                self._embed = jax.jit(functools.partial(
+                    twr.apply, self._spec, rules=self._rules))
+                self.route_stats = route_stats(self._spec)
+                self.routes = None
+            else:
+                self._fwd, self._bwd = _make_member_fns(self._spec,
+                                                        self._rules)
+                self._embed = self._fwd
             self.masker = None
             # mask-stream namespace for predict queries: every member
             # sees the same EVAL round sequence, so a shared counter
@@ -279,13 +328,19 @@ class SplitNNProtocol(VFLProtocol):
             return float(loss)
 
     def member_stage_send(self, rows, step):
-        """Bottom forward + activation isend; the batch slice is the ctx
-        the deferred backward stage reuses (its VJP must see the inputs
+        """Bottom forward + activation isend; the batch slice (with the
+        expert choices, for a routed tower) is the ctx the deferred
+        backward stage reuses (its VJP must see the inputs and routing
         this forward actually saw)."""
         with obs.span(self._sp_gather, step=step):
             (xb,) = _take((self.x,), rows)
         with obs.span(self._sp_step, step=step):
-            u = self._fwd(self.params, xb)
+            if self._routed:
+                u, self.routes, self.route_stats = self._fwd(
+                    self.params, xb, self.route_stats)
+                xb = (xb, self.routes)
+            else:
+                u = self._fwd(self.params, xb)
         if self.cfg.noise_sigma > 0:
             # noising defense (docs/privacy.md): the member perturbs
             # its outgoing embedding before any masking, so neither the
@@ -308,7 +363,12 @@ class SplitNNProtocol(VFLProtocol):
         with obs.span(self._sp_h2d, step=step):
             du = jnp.asarray(du, jnp.float32)
         with obs.span(self._sp_step, step=step):
-            self.params = self._bwd(self.params, xb, du, self.lr)
+            if self._routed:
+                xb, routes = xb
+                self.params = self._bwd(self.params, xb, du, self.lr,
+                                        routes)
+            else:
+                self.params = self._bwd(self.params, xb, du, self.lr)
 
     # -- predict/serve -------------------------------------------------------
     def predict_master(self, rows) -> np.ndarray:
@@ -334,7 +394,7 @@ class SplitNNProtocol(VFLProtocol):
         with obs.span(self._sp_gather):
             (xb,) = _take((self.x,), rows)
         with obs.span(self._sp_step):
-            u = self._fwd(self.params, xb)
+            u = self._embed(self.params, xb)
         with obs.span(self._sp_d2h):
             return np.asarray(u)
 

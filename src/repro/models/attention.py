@@ -60,9 +60,11 @@ def _mask(q_pos, k_pos, window: int, causal: bool):
     return m
 
 
-def _sdpa(q, k, v, mask) -> jax.Array:
-    """q: (b,sq,kv,g,hd) k/v: (b,sk,kv,hd); grouped-query attention core."""
-    scale = q.shape[-1] ** -0.5
+def _sdpa(q, k, v, mask, scale=None) -> jax.Array:
+    """q: (b,sq,kv,g,hd) k/v: (b,sk,kv,hd); grouped-query attention core.
+    ``scale`` multiplies the logits (default ``1/sqrt(hd)``)."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqngd,bkn d->bnqgk".replace(" ", ""),
                         q.astype(jnp.float32) * scale,
                         k.astype(jnp.float32))
@@ -73,7 +75,7 @@ def _sdpa(q, k, v, mask) -> jax.Array:
 
 
 def attend(q, k, v, q_pos, k_pos, *, window=0, causal=True,
-           q_chunk: int = 2048) -> jax.Array:
+           q_chunk: int = 2048, scale=None) -> jax.Array:
     """Chunked-over-queries masked attention.
 
     q: (b, sq, h, hd); k/v: (b, sk, kv, hd). Returns (b, sq, h, hd).
@@ -86,7 +88,7 @@ def attend(q, k, v, q_pos, k_pos, *, window=0, causal=True,
 
     if sq <= q_chunk:
         mask = _mask(q_pos, k_pos, window, causal)[None]
-        return _sdpa(qg, k, v, mask).reshape(b, sq, h, vd)
+        return _sdpa(qg, k, v, mask, scale).reshape(b, sq, h, vd)
 
     n_chunks = sq // q_chunk
     assert sq % q_chunk == 0, (sq, q_chunk)
@@ -96,7 +98,7 @@ def attend(q, k, v, q_pos, k_pos, *, window=0, causal=True,
     def one(args):
         qi, pi = args
         mask = _mask(pi, k_pos, window, causal)[None]
-        return _sdpa(qi, k, v, mask)
+        return _sdpa(qi, k, v, mask, scale)
 
     out = jax.lax.map(one, (qc.swapaxes(0, 1), pc))      # (n, b, qc, kv, g, vd)
     return out.swapaxes(0, 1).reshape(b, sq, h, vd)

@@ -6,10 +6,12 @@ activation dtype with fp32 accumulation where it matters.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.params import Spec
 from repro.sharding.rules import reduce_dtype
@@ -50,18 +52,66 @@ def layernorm(params, x, eps: float = 1e-5):
 # ---------------------------------------------------------------------------
 
 
-def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature for a context stretched ``factor``
+    times (1 where nothing is stretched)."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_ramp(scaling, head_dim: int, theta: float) -> np.ndarray:
+    """Per frequency, 0 where YaRN interpolates (stretches) it and 1 where
+    it keeps it: a linear ramp between the dims that turn ``beta_fast``
+    and ``beta_slow`` times over the original context."""
+    def dim_of(rotations):
+        return (head_dim * math.log(scaling.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim_of(scaling.beta_fast)), 0)
+    high = min(math.ceil(dim_of(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    i = np.arange(head_dim // 2, dtype=np.float64)
+    return 1.0 - np.clip((i - low) / (high - low), 0.0, 1.0)
+
+
+def rope_frequencies(head_dim: int, theta: float, scaling=None) -> jax.Array:
+    """Rotary frequencies, computed in float64 on the host and rounded
+    once to float32 (at position p the angle is ``p * freq``, so an ulp
+    lost here grows with the position); ``scaling`` (a ``YarnConfig`` or
+    None) blends each with its ``factor``-stretched copy by YaRN's
+    ramp."""
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    freqs = 1.0 / theta ** (np.arange(half, dtype=np.float64) / half)
+    if scaling is not None:
+        keep = _yarn_ramp(scaling, head_dim, theta)
+        freqs = freqs / scaling.factor * (1.0 - keep) + freqs * keep
+    return jnp.asarray(freqs, jnp.float32)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: (..., seq) int32."""
+def attn_scale(scaling) -> float:
+    """Factor on the attention logits' ``1/sqrt(d)``: YaRN's
+    ``mscale(factor, mscale_all_dim)**2`` (1 without scaling)."""
+    if scaling is None or not scaling.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: (..., seq) int32.
+    ``scaling``: a ``YarnConfig`` or None (plain rope)."""
     head_dim = x.shape[-1]
-    freqs = rope_frequencies(head_dim, theta)                # (hd/2,)
+    freqs = rope_frequencies(head_dim, theta, scaling)       # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., s, hd/2)
     cos = jnp.cos(angles)[..., None, :]                      # (..., s, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

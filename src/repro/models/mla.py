@@ -1,10 +1,11 @@
 """Multi-head latent attention (DeepSeek-V2 / MiniCPM3).
 
 Faithful points: low-rank compressed KV latent (kv_lora_rank) with RMSNorm,
-decoupled RoPE key shared across heads, optional low-rank Q. The decode
-path stores ONLY the compressed latent + rope key (the MLA memory win) and
-uses the absorbed-weight formulation so per-step compute is
-O(S * kv_lora_rank) per head, never materializing full K/V.
+decoupled RoPE key shared across heads, optional low-rank Q, and YaRN
+rope scaling (``cfg.rope_scaling``) with its mscale on the softmax scale.
+The decode path stores ONLY the compressed latent + rope key (the MLA
+memory win) and uses the absorbed-weight formulation so per-step compute
+is O(S * kv_lora_rank) per head, never materializing full K/V.
 """
 from __future__ import annotations
 
@@ -62,8 +63,16 @@ def _queries(cfg, params, x, positions):
     else:
         q_nope = jnp.einsum("bsd,dhk->bshk", x, params["wq_nope"])
         q_rope = jnp.einsum("bsd,dhk->bshk", x, params["wq_rope"])
-    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta,
+                               cfg.rope_scaling)
     return q_nope, q_rope
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    """``1/sqrt(qk head dim)``, times YaRN's mscale squared."""
+    m = cfg.mla
+    return ((m.nope_head_dim + m.rope_head_dim) ** -0.5
+            * layers.attn_scale(cfg.rope_scaling))
 
 
 def mla_self_attention(cfg: ModelConfig, params, x, *, positions=None
@@ -77,7 +86,8 @@ def mla_self_attention(cfg: ModelConfig, params, x, *, positions=None
                          jnp.einsum("bsd,dr->bsr", x, params["w_dkv"]),
                          cfg.norm_eps)
     k_rope = jnp.einsum("bsd,dk->bsk", x, params["w_kr"])[:, :, None, :]
-    k_rope = layers.apply_rope(k_rope, positions[None], cfg.rope_theta)
+    k_rope = layers.apply_rope(k_rope, positions[None], cfg.rope_theta,
+                               cfg.rope_scaling)
     q_nope, q_rope = _queries(cfg, params, x, positions[None])
 
     k_nope = jnp.einsum("bsr,rhk->bshk", ckv, params["w_uk"])
@@ -87,7 +97,8 @@ def mla_self_attention(cfg: ModelConfig, params, x, *, positions=None
         [k_nope, jnp.broadcast_to(k_rope,
                                   (b, s, cfg.eff_heads, m.rope_head_dim))],
         axis=-1)
-    out = attend(q, k, v, positions, positions, window=0, causal=True)
+    out = attend(q, k, v, positions, positions, window=0, causal=True,
+                 scale=softmax_scale(cfg))
     return jnp.einsum("bshk,hkd->bsd", out, params["wo"],
                       preferred_element_type=reduce_dtype(out.dtype))
 
@@ -115,7 +126,8 @@ def mla_decode_attention(cfg: ModelConfig, params, x, cache, index
                            jnp.einsum("bsd,dr->bsr", x, params["w_dkv"]),
                            cfg.norm_eps)
     kr_t = jnp.einsum("bsd,dk->bsk", x, params["w_kr"])[:, :, None, :]
-    kr_t = layers.apply_rope(kr_t, pos, cfg.rope_theta)[:, :, 0, :]
+    kr_t = layers.apply_rope(kr_t, pos, cfg.rope_theta,
+                             cfg.rope_scaling)[:, :, 0, :]
     ckv = jax.lax.dynamic_update_slice_in_dim(
         cache["ckv"], ckv_t.astype(cache["ckv"].dtype), index, axis=1)
     k_rope = jax.lax.dynamic_update_slice_in_dim(
@@ -124,7 +136,7 @@ def mla_decode_attention(cfg: ModelConfig, params, x, cache, index
     q_nope, q_rope = _queries(cfg, params, x, pos)
     # absorb W_uk into the query: score contraction happens in latent space
     q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, params["w_uk"])
-    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    scale = softmax_scale(cfg)
     scores = (jnp.einsum("bshr,bSr->bhsS", q_lat.astype(jnp.float32),
                          ckv.astype(jnp.float32))
               + jnp.einsum("bshk,bSk->bhsS", q_rope.astype(jnp.float32),

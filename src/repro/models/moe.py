@@ -1,4 +1,6 @@
-"""Mixture-of-experts FFN with top-k routing and capacity-based dispatch.
+"""Mixture-of-experts FFN with top-k routing: capacity-based dispatch for
+the scanned LLM stacks, and a dropless expert share for a tower that
+holds some of the experts (:func:`moe_share`).
 
 Dispatch is scatter-based (memory-safe): tokens are placed into a
 per-expert capacity buffer with ``.at[].add`` using positions from a
@@ -9,6 +11,10 @@ collective. Shared experts (DeepSeek style) run densely on every token.
 
 Aux losses: GShard load-balance loss and router z-loss, returned per call
 and averaged over layers by the caller.
+
+Gating follows the HF config's keys on ``MoEConfig``: softmax scores,
+top-k, renormalized only where ``norm_topk_prob``, times
+``routed_scaling_factor``.
 """
 from __future__ import annotations
 
@@ -45,6 +51,30 @@ def moe_spec(cfg: ModelConfig):
     return spec
 
 
+def gate_scores(x, router) -> jax.Array:
+    """The router's softmax scores over every expert, in float32."""
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32), router)
+    return jax.nn.softmax(logits, axis=-1)
+
+
+def top_experts(scores, k: int) -> jax.Array:
+    """Greedy top-k: each token's ``k`` highest-scoring experts."""
+    return jax.lax.top_k(scores, k)[1]
+
+
+def gates(m, top_scores) -> jax.Array:
+    """Weights of the chosen experts from their scores (``m``: a
+    ``MoEConfig``)."""
+    if m.scoring_func != "softmax":
+        raise ValueError(f"scoring_func {m.scoring_func!r}: only softmax")
+    if m.norm_topk_prob:
+        top_scores = top_scores / jnp.maximum(
+            top_scores.sum(-1, keepdims=True), 1e-9)
+    if m.routed_scaling_factor != 1.0:
+        top_scores = top_scores * m.routed_scaling_factor
+    return top_scores
+
+
 def _capacity(n_tokens: int, cfg: ModelConfig) -> int:
     m = cfg.moe
     c = int(n_tokens * m.top_k * m.capacity_factor / m.num_experts)
@@ -72,8 +102,7 @@ def moe_ffn_global(cfg: ModelConfig, params, x, act: str = "silu"
                         params["router"])                       # (t, E)
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, sel = jax.lax.top_k(probs, m.top_k)              # (t, k)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)                 # renormalize
+    gate_vals = gates(m, gate_vals)
 
     # --- aux losses (GShard) ---------------------------------------------
     me = probs.mean(axis=0)                                     # (E,)
@@ -146,8 +175,7 @@ def moe_ffn_grouped(cfg: ModelConfig, params, x, act: str = "silu"
                         params["router"])
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, sel = jax.lax.top_k(probs, m.top_k)          # (b, s, k)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    gate_vals = gates(m, gate_vals)
 
     me = probs.mean(axis=(0, 1))
     ce = jax.nn.one_hot(sel[..., 0], m.num_experts).mean(axis=(0, 1))
@@ -199,3 +227,114 @@ def moe_ffn_grouped(cfg: ModelConfig, params, x, act: str = "silu"
     if m.num_shared:
         y = y + layers.gated_mlp(params["shared"], x, act)
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# expert share: the experts one chip holds, routed over all, dropless
+# ---------------------------------------------------------------------------
+
+
+def share_rows(tokens: int, top_k: int, held: int) -> int:
+    """Rows of the sorted buffer: every assignment a token can make to a
+    held expert, so that no routing drops one."""
+    return tokens * min(top_k, held)
+
+
+def expert_ffn(xs, params, sizes, act: str = "silu"):
+    """Each held expert's gated MLP over its rows: ``xs`` sorted by
+    expert, ``sizes[j]`` rows for the ``j``-th held expert, then rows of
+    no expert (their output is left undefined). Grouped products over
+    ragged groups (``jax.lax.ragged_dot``), no capacity buffer."""
+    a = jax.lax.ragged_dot(xs, params["w_gate"], sizes)
+    u = jax.lax.ragged_dot(xs, params["w_up"], sizes)
+    h = layers._act(act)(a) * u
+    return jax.lax.ragged_dot(h, params["w_down"], sizes)
+
+
+def moe_share(m, params, x, *, first: int, routes=None,
+              act: str = "silu") -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """A dropless share of an expert-parallel MoE FFN (DeepSeek-V2).
+
+    ``x``: (t, d). ``params``: ``router`` (d, E) over all ``E`` experts,
+    ``w_gate``/``w_up`` (held, d, f) and ``w_down`` (held, f, d) of the
+    experts ``first .. first + held - 1`` this share holds, and
+    ``shared`` (the always-on experts' gated MLP) where ``m.num_shared``.
+    Every token routes over all ``E`` experts with ``m``'s gating (or by
+    ``routes``, (t, k) expert ids, where given); the share computes its
+    held experts' part for the rows routed to them, sorted by expert into
+    ragged groups, plus the shared experts. Absent experts add nothing
+    here: summed over all shares, with the shared experts once, the
+    result is the whole layer's.
+
+    Returns ``(y, aux)``: ``aux["routes"]`` (t, k) int32 expert ids,
+    ``aux["rows"]`` (held,) int32 rows routed to each held expert,
+    ``aux["dropped"]`` () int32 routed rows left uncomputed (0).
+    """
+    held = params["w_gate"].shape[0]
+    t = x.shape[0]
+    k = m.top_k
+    with jax.named_scope("moe.route"):
+        scores = gate_scores(x, params["router"])
+        sel = top_experts(scores, k) if routes is None else routes
+        g = gates(m, jnp.take_along_axis(scores, sel, axis=-1))
+        local = sel.reshape(-1) - first
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)                  # held = none
+        order = jnp.argsort(key, stable=True)[:share_rows(t, k, held)]
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+        valid = jnp.arange(order.shape[0]) < sizes.sum()
+        tok = order // k
+    with jax.named_scope("moe.experts"):
+        # rows past the groups are zeroed both ways, so that what a
+        # grouped product leaves there never reaches x or its gradient
+        xs = jnp.where(valid[:, None], x[tok], 0.0)
+        o = expert_ffn(xs, params, sizes, act)
+        w = jnp.where(valid, g.reshape(-1)[order], 0.0).astype(o.dtype)
+        o = jnp.where(valid[:, None], o, 0.0) * w[:, None]
+        y = jnp.zeros_like(x).at[tok].add(o)
+    if m.num_shared:
+        with jax.named_scope("moe.shared"):
+            y = y + layers.gated_mlp(params["shared"], x, act)
+    aux = {"routes": sel.astype(jnp.int32), "rows": sizes,
+           "dropped": (mine.sum() - valid.sum()).astype(jnp.int32)}
+    return y, aux
+
+
+def init_share(key, m, d: int, first: int, held: int) -> Dict[str, jax.Array]:
+    """Weights of a share: the router over all experts, the held experts
+    ``first .. first + held - 1`` (each drawn from its own global index,
+    so every share holds the same experts the whole layer would) and the
+    shared experts; normal over the square root of the fan-in."""
+    f = m.d_expert
+    n = jax.random.normal
+    ks = [jax.random.fold_in(key, i) for i in range(5)]
+
+    def experts(k, shape, fan_in):
+        return jax.vmap(lambda e: n(jax.random.fold_in(k, e), shape,
+                                    jnp.float32))(
+            first + jnp.arange(held)) / jnp.sqrt(float(fan_in))
+    out = {"router": n(ks[0], (d, m.num_experts), jnp.float32)
+           / jnp.sqrt(float(d)),
+           "w_gate": experts(ks[1], (d, f), d),
+           "w_up": experts(ks[2], (d, f), d),
+           "w_down": experts(ks[3], (f, d), f)}
+    if m.num_shared:
+        fs = m.num_shared * f
+        k = [jax.random.fold_in(ks[4], i) for i in range(3)]
+        out["shared"] = {
+            "w_gate": n(k[0], (d, fs), jnp.float32) / jnp.sqrt(float(d)),
+            "w_up": n(k[1], (d, fs), jnp.float32) / jnp.sqrt(float(d)),
+            "w_down": n(k[2], (fs, d), jnp.float32) / jnp.sqrt(float(fs))}
+    return out
+
+
+def share_axes(m) -> Dict[str, object]:
+    """Logical axes of :func:`init_share`'s tree."""
+    out = {"router": ("embed", None),
+           "w_gate": ("experts", "embed", "expert_mlp"),
+           "w_up": ("experts", "embed", "expert_mlp"),
+           "w_down": ("experts", "expert_mlp", "embed")}
+    if m.num_shared:
+        out["shared"] = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"),
+                         "w_down": ("mlp", "embed")}
+    return out

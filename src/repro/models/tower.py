@@ -30,6 +30,24 @@ Block kinds
                The final block of every tower must be an ``mlp`` (it
                owns the output width).
 
+Decoder blocks over token ids (DeepSeek-V2 style, ``docs/models.md``):
+
+``tokens``     token-embedding lookup: the silo holds int32 token ids
+               (its width is the sequence length). Must be first.
+``mla``        pre-norm residual multi-head latent attention
+               (``models/mla.py``), causal, with YaRN rope scaling.
+``ffn``        pre-norm residual dense SiLU-gated FFN.
+``moe``        pre-norm residual MoE FFN that holds ``held`` of the
+               ``experts`` (those from ``shard * held``), routes over all
+               of them and computes its own experts' part, dropless
+               (``models/moe.py`` ``moe_share``), plus the shared experts.
+``rmsnorm``    a bare RMSNorm (the stack's final norm).
+
+``mla``/``ffn``/``moe`` recompute their forward in the backward pass,
+keeping only each block's input (at 16 users x 512 tokens six
+DeepSeek-V2-Lite layers' activations would otherwise take ~10 GB of a
+16 GB chip).
+
 Blocks are written either as dicts or as compact strings
 ``"kind:key=val,key=val"`` with ``|``-separated integer tuples::
 
@@ -54,7 +72,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-BLOCK_KINDS = ("embed", "attn_block", "quantize", "mlp")
+from repro.configs.base import MLAConfig, ModelConfig, MoEConfig, YarnConfig
+from repro.models import layers, mla, moe
+from repro.models import params as prm
+
+BLOCK_KINDS = ("embed", "attn_block", "quantize", "mlp", "tokens", "mla",
+               "ffn", "moe", "rmsnorm")
+# blocks that run on a (batch, seq, dim) sequence
+_SEQ_KINDS = ("attn_block", "mla", "ffn", "moe", "rmsnorm")
+_FIRST_KINDS = ("embed", "tokens")
 
 # embed-block bucketization: chunk means of standardized features live
 # almost entirely in [-2.5, 2.5]; that range maps linearly onto the
@@ -135,6 +161,14 @@ _BLOCK_KEYS = {
     "attn_block": {"heads", "mlp", "kernel"},
     "quantize": {"kernel"},
     "mlp": {"hidden", "final_act"},
+    "tokens": {"vocab", "dim"},
+    "mla": {"heads", "kv_lora", "nope", "rope", "v", "theta",
+            "yarn_factor", "yarn_original", "beta_fast", "beta_slow",
+            "mscale", "mscale_all_dim", "eps"},
+    "ffn": {"hidden", "eps"},
+    "moe": {"experts", "held", "shard", "top_k", "hidden", "shared",
+            "norm_topk", "scaling", "eps"},
+    "rmsnorm": {"eps"},
 }
 
 
@@ -154,17 +188,17 @@ def check_blocks(blocks: Sequence[BlockLike]) -> List[Dict[str, Any]]:
         if extra:
             raise ValueError(
                 f"tower block {i} ({kind}): unknown keys {sorted(extra)}")
-        if kind == "embed" and i != 0:
-            raise ValueError("'embed' must be the first tower block")
-        if kind == "attn_block":
-            if not parsed[:i] or parsed[0]["kind"] != "embed":
+        if kind in _FIRST_KINDS and i != 0:
+            raise ValueError(f"{kind!r} must be the first tower block")
+        if kind in _SEQ_KINDS:
+            if not parsed[:i] or parsed[0]["kind"] not in _FIRST_KINDS:
                 raise ValueError(
-                    "'attn_block' needs an 'embed' block first "
-                    "(attention runs on the token sequence it "
-                    "produces)")
+                    f"{kind!r} needs an 'embed' block first (or "
+                    f"'tokens': it runs on the token sequence it "
+                    f"produces)")
             if any(p["kind"] == "mlp" for p in parsed[:i]):
                 raise ValueError(
-                    "'attn_block' must come before any 'mlp' block — "
+                    f"{kind!r} must come before any 'mlp' block — "
                     "'mlp' mean-pools the token sequence to flat "
                     "features, leaving no sequence to attend over")
         if b.get("kernel", "auto") not in ("auto", "pallas", "ref"):
@@ -218,6 +252,16 @@ def resolve(blocks: Sequence[BlockLike], in_dim: int,
         elif kind == "quantize":
             resolved.append({"kind": "quantize",
                              "kernel": b.get("kernel", "auto")})
+        elif kind == "tokens":
+            vocab, dim = int(b.get("vocab", 0)), int(b.get("dim", 0))
+            if vocab < 1 or dim < 1:
+                raise ValueError(f"tokens block: vocab and dim >= 1 "
+                                 f"required, got {vocab}/{dim}")
+            resolved.append({"kind": "tokens", "vocab": vocab, "dim": dim,
+                             "seq": width})
+            width, seq = dim, width
+        elif kind in ("mla", "ffn", "moe", "rmsnorm"):
+            resolved.append(_RESOLVE[kind](b, width, seq))
         else:  # mlp
             hidden = b.get("hidden", ())
             if isinstance(hidden, int):
@@ -354,6 +398,73 @@ fake_quant.defvjp(_fq_fwd, _fq_bwd)
 
 
 # ---------------------------------------------------------------------------
+# decoder blocks over token ids: resolution
+# ---------------------------------------------------------------------------
+
+
+def _eps(b) -> float:
+    return float(b.get("eps", 1e-6))
+
+
+def _resolve_mla(b, width, seq):
+    heads = int(b.get("heads", 0))
+    if heads < 1:
+        raise ValueError("mla block: heads >= 1 required")
+    yarn = None
+    if b.get("yarn_factor"):
+        yarn = YarnConfig(
+            factor=float(b["yarn_factor"]),
+            original_max_position=int(b["yarn_original"]),
+            beta_fast=float(b.get("beta_fast", 32)),
+            beta_slow=float(b.get("beta_slow", 1)),
+            mscale=float(b.get("mscale", 1)),
+            mscale_all_dim=float(b.get("mscale_all_dim", 0)))
+    m = MLAConfig(kv_lora_rank=int(b["kv_lora"]), q_lora_rank=None,
+                  rope_head_dim=int(b["rope"]),
+                  nope_head_dim=int(b["nope"]), v_head_dim=int(b["v"]))
+    cfg = ModelConfig(
+        arch_id="tower-mla", family="moe", source="tower block",
+        n_layers=1, d_model=width, d_ff=width, vocab=1, n_heads=heads,
+        n_kv_heads=heads, head_dim=m.nope_head_dim, attention="mla",
+        mla=m, rope_theta=float(b.get("theta", 10_000)),
+        rope_scaling=yarn, norm_eps=_eps(b))
+    return {"kind": "mla", "dim": width, "seq": seq, "heads": heads,
+            "eps": _eps(b), "cfg": cfg}
+
+
+def _resolve_ffn(b, width, seq):
+    return {"kind": "ffn", "dim": width, "seq": seq,
+            "hidden": int(b["hidden"]), "eps": _eps(b)}
+
+
+def _resolve_moe(b, width, seq):
+    experts, top_k = int(b["experts"]), int(b["top_k"])
+    held = int(b.get("held", experts))
+    shard = int(b.get("shard", 0))
+    if not 0 < top_k <= experts or not 0 < held <= experts \
+            or not 0 <= shard * held <= experts - held:
+        raise ValueError(
+            f"moe block: need 0 < top_k, held <= experts and the shard's "
+            f"experts inside them, got experts={experts} held={held} "
+            f"shard={shard} top_k={top_k}")
+    m = MoEConfig(num_experts=experts, top_k=top_k,
+                  d_expert=int(b["hidden"]),
+                  num_shared=int(b.get("shared", 0)),
+                  norm_topk_prob=bool(b.get("norm_topk", 1)),
+                  routed_scaling_factor=float(b.get("scaling", 1)))
+    return {"kind": "moe", "dim": width, "seq": seq, "held": held,
+            "first": shard * held, "eps": _eps(b), "moe": m}
+
+
+def _resolve_rmsnorm(b, width, seq):
+    return {"kind": "rmsnorm", "dim": width, "eps": _eps(b)}
+
+
+_RESOLVE = {"mla": _resolve_mla, "ffn": _resolve_ffn, "moe": _resolve_moe,
+            "rmsnorm": _resolve_rmsnorm}
+
+
+# ---------------------------------------------------------------------------
 # init / apply
 # ---------------------------------------------------------------------------
 
@@ -414,22 +525,90 @@ def _init_attn(b, key):
     }
 
 
+def _norm_scale(dim):
+    return {"scale": jnp.ones((dim,), jnp.float32)}
+
+
+def _gated(key, d, f):
+    ks = [jax.random.fold_in(key, i) for i in range(3)]
+    n = jax.random.normal
+    return {"w_gate": n(ks[0], (d, f), jnp.float32) / np.sqrt(d),
+            "w_up": n(ks[1], (d, f), jnp.float32) / np.sqrt(d),
+            "w_down": n(ks[2], (f, d), jnp.float32) / np.sqrt(f)}
+
+
+def _init_tokens(b, key):
+    return {"table": 0.02 * jax.random.normal(
+        key, (b["vocab"], b["dim"]), jnp.float32)}
+
+
+def _init_mla(b, key):
+    return {"norm": _norm_scale(b["dim"]),
+            "attn": prm.init_tree(mla.mla_spec(b["cfg"]), key)}
+
+
+def _init_ffn(b, key):
+    return {"norm": _norm_scale(b["dim"]),
+            "mlp": _gated(key, b["dim"], b["hidden"])}
+
+
+def _init_moe(b, key):
+    return {"norm": _norm_scale(b["dim"]),
+            **moe.init_share(key, b["moe"], b["dim"], b["first"],
+                             b["held"])}
+
+
 _BLOCK_INIT = {"mlp": _init_mlp, "embed": _init_embed,
                "attn_block": _init_attn,
-               "quantize": lambda b, key: {}}
+               "quantize": lambda b, key: {},
+               "tokens": _init_tokens, "mla": _init_mla, "ffn": _init_ffn,
+               "moe": _init_moe,
+               "rmsnorm": lambda b, key: _norm_scale(b["dim"])}
 
 
 def apply(spec: TowerSpec, params: Sequence[Any], x,
           rules=None):
     """Pure forward pass. ``rules`` (a ``MeshRules`` or None) is threaded
     explicitly — contextvars don't survive jit tracing boundaries."""
+    return _run(spec, params, x, rules)[0]
+
+
+def apply_routed(spec: TowerSpec, params: Sequence[Any], x, routes=None,
+                 rules=None):
+    """Forward pass of a tower with ``moe`` blocks: ``(y, aux)``.
+
+    ``aux["routes"]``: each ``moe`` block's (tokens, top_k) int32 expert
+    choices; ``aux["rows"]``: each one's (held,) rows routed to its held
+    experts; ``aux["dropped"]``: routed rows left uncomputed (0). Given
+    ``routes`` (a previous call's), the blocks route by them instead of
+    choosing again: a backward pass then differentiates exactly the
+    forward that was sent."""
+    y, sels, rows, dropped = _run(spec, params, x, rules, routes)
+    return y, {"routes": tuple(sels), "rows": tuple(rows),
+               "dropped": sum(dropped, jnp.zeros((), jnp.int32))}
+
+
+def routed_blocks(spec: TowerSpec) -> List[Dict[str, Any]]:
+    """The tower's ``moe`` blocks, in order."""
+    return [b for b in spec.blocks if b["kind"] == "moe"]
+
+
+def _run(spec, params, x, rules=None, routes=None):
+    sels, rows, dropped = [], [], []
     for b, p in zip(spec.blocks, params):
-        x = _BLOCK_APPLY[b["kind"]](b, p, x, rules)
+        if b["kind"] == "moe":
+            r = None if routes is None else routes[len(sels)]
+            x, aux = _apply_moe(b, p, x, r)
+            sels.append(aux["routes"])
+            rows.append(aux["rows"])
+            dropped.append(aux["dropped"])
+        else:
+            x = _BLOCK_APPLY[b["kind"]](b, p, x, rules)
         if x.ndim == 3:
             x = _constrain(x, ("batch", None, None), rules)
         else:
             x = _constrain(x, ("batch", "mlp"), rules)
-    return x
+    return x, sels, rows, dropped
 
 
 def _shard(rules, logical, shape):
@@ -501,8 +680,45 @@ def _apply_quant(b, p, x, rules=None):
     return fake_quant(x, b["kernel"], _shard(rules, ("batch", None), flat))
 
 
+def _apply_tokens(b, p, x, rules=None):
+    return p["table"][x]
+
+
+def _apply_mla(b, p, x, rules=None):
+    def block(p, x):
+        with jax.named_scope("mla"):
+            h = layers.rmsnorm(p["norm"], x, b["eps"])
+            return x + mla.mla_self_attention(b["cfg"], p["attn"], h)
+    return jax.checkpoint(block)(p, x)
+
+
+def _apply_ffn(b, p, x, rules=None):
+    def block(p, x):
+        with jax.named_scope("ffn.dense"):
+            h = layers.rmsnorm(p["norm"], x, b["eps"])
+            return x + layers.gated_mlp(p["mlp"], h, "silu")
+    return jax.checkpoint(block)(p, x)
+
+
+def _apply_moe(b, p, x, routes=None):
+    n, s, d = x.shape
+
+    def block(p, x, routes):
+        h = layers.rmsnorm(p["norm"], x, b["eps"]).reshape(n * s, d)
+        y, aux = moe.moe_share(b["moe"], p, h, first=b["first"],
+                               routes=routes)
+        return x + y.reshape(n, s, d), aux
+    return jax.checkpoint(block)(p, x, routes)
+
+
+def _apply_rmsnorm(b, p, x, rules=None):
+    return layers.rmsnorm(p, x, b["eps"])
+
+
 _BLOCK_APPLY = {"mlp": _apply_mlp, "embed": _apply_embed,
-                "attn_block": _apply_attn, "quantize": _apply_quant}
+                "attn_block": _apply_attn, "quantize": _apply_quant,
+                "tokens": _apply_tokens, "mla": _apply_mla,
+                "ffn": _apply_ffn, "rmsnorm": _apply_rmsnorm}
 
 
 # ---------------------------------------------------------------------------
@@ -522,6 +738,21 @@ def logical_axes(spec: TowerSpec) -> List[Any]:
             axes.append({"w": (None, None, "mlp"),
                          "table": ("vocab", None),
                          "pos": (None, None)})
+        elif kind == "tokens":
+            axes.append({"table": ("vocab", None)})
+        elif kind == "mla":
+            axes.append({"norm": {"scale": (None,)},
+                         "attn": prm.axes_tree(mla.mla_spec(b["cfg"]))})
+        elif kind == "ffn":
+            axes.append({"norm": {"scale": (None,)},
+                         "mlp": {"w_gate": ("embed", "mlp"),
+                                 "w_up": ("embed", "mlp"),
+                                 "w_down": ("mlp", "embed")}})
+        elif kind == "moe":
+            axes.append({"norm": {"scale": (None,)},
+                         **moe.share_axes(b["moe"])})
+        elif kind == "rmsnorm":
+            axes.append({"scale": (None,)})
         elif kind == "attn_block":
             axes.append({"ln1": (None,),
                          "wq": ("embed", "heads"),
@@ -585,7 +816,32 @@ def tower_flops(spec: TowerSpec, batch: int) -> float:
             fl += 8.0 * n * t * d * d          # qkv + out projections
             fl += 4.0 * n * t * t * d          # scores + weighted sum
             fl += 4.0 * n * t * d * f          # relu MLP
+        elif b["kind"] == "mla":
+            fl += n * b["seq"] * _mla_token_flops(b["cfg"], b["seq"])
+        elif b["kind"] == "ffn":
+            fl += 6.0 * n * b["seq"] * b["dim"] * b["hidden"]
+        elif b["kind"] == "moe":
+            m, d = b["moe"], b["dim"]
+            # the held experts' expected share of each token's top_k
+            routed = m.top_k * b["held"] / m.num_experts
+            fl += n * b["seq"] * (2.0 * d * m.num_experts
+                                  + 6.0 * d * m.d_expert
+                                  * (m.num_shared + routed))
     return fl
+
+
+def _mla_token_flops(cfg, seq: int) -> float:
+    """Forward FLOPs of one token of an MLA block over ``seq`` keys:
+    its projections, and scores and weighted sum over every key (the
+    causal mask not subtracted, as for ``attn_block``)."""
+    m, d, h = cfg.mla, cfg.d_model, cfg.n_heads
+    qk = m.nope_head_dim + m.rope_head_dim
+    q = d * h * qk
+    kv = d * (m.kv_lora_rank + m.rope_head_dim) \
+        + m.kv_lora_rank * h * (m.nope_head_dim + m.v_head_dim)
+    out = h * m.v_head_dim * d
+    attn = seq * h * (qk + m.v_head_dim)
+    return 2.0 * (q + kv + out + attn)
 
 
 def params_bytes(params) -> int:

@@ -55,3 +55,32 @@ def test_quantize_int8_compiles_for_v5e(one_chip, rows):
     compiled = ops.quantize_int8.lower(_sds((rows, 64), one_chip),
                                        interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the MoE share's grouped expert products (``moe.expert_ffn``) at
+# DeepSeek-V2-Lite's widths: 8 held experts of width 1,408 over d 2,048,
+# with the dropless buffer of 16 users x 512 tokens x top-6 rows, and a
+# one-user buffer; forward and the gradient of both operands
+@pytest.mark.parametrize("rows", [49152, 3072])
+@pytest.mark.parametrize("grad", [False, True])
+def test_expert_grouped_product_compiles_for_v5e(one_chip, rows, grad):
+    from repro.models import moe
+    d, f, held = 2048, 1408, 8
+    xs = _sds((rows, d), one_chip)
+    params = {"w_gate": _sds((held, d, f), one_chip),
+              "w_up": _sds((held, d, f), one_chip),
+              "w_down": _sds((held, f, d), one_chip)}
+    sizes = jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
+
+    def fwd(xs, params, sizes):
+        return moe.expert_ffn(xs, params, sizes)
+    fn = fwd
+    if grad:
+        def fn(xs, params, sizes):
+            return jax.grad(lambda x, p: jnp.sum(fwd(x, p, sizes)),
+                            (0, 1))(xs, params)
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(fn).lower(xs, params, sizes).compile().as_text()
+    # the grouped products are the chip's ragged-dot kernel, not a dense
+    # product over every expert
+    assert "ragged-dot" in text and "tpu_custom_call" in text

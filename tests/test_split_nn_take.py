@@ -56,6 +56,26 @@ def test_take_equals_eager_indexing_bit_for_bit(party, rows):
                               want.view(np.uint32))
 
 
+@pytest.mark.parametrize("rows", ["batch", "tail", "serve-1", "serve-64"])
+@pytest.mark.parametrize("width", [512, 100])
+def test_int32_token_silo_take_equals_eager_indexing(width, rows):
+    """A member's token-id silo is stored and taken as int32, never
+    through a float cast: the take gives the ids eager indexing gives."""
+    rng = np.random.default_rng(1)
+    host = rng.integers(0, 102400, size=(N_SILO, width)).astype(np.int32)
+    stored = split_nn._Silo.put(host)
+    assert stored.data.dtype == jnp.int32 and stored.width == width
+    assert stored.data.shape[1] % split_nn.LANES == 0
+    idx = _rows(rows)
+    (got,) = split_nn._take((stored,), idx)
+    assert got.dtype == jnp.int32 and got.shape == (len(idx), width)
+    assert np.array_equal(np.asarray(got), host[idx])
+    # ids above 2**24 would not survive a float32 round trip
+    big = np.full((4, 3), 2**24 + 1, np.int32)
+    (back,) = split_nn._take((split_nn._Silo.put(big),), [0, 3])
+    assert np.array_equal(np.asarray(back), big[[0, 3]])
+
+
 def _dataset(n, d=12, items=3, seed=0):
     rng = np.random.default_rng(seed)
     x = rng.normal(size=(n, d))
