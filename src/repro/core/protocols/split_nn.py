@@ -184,23 +184,28 @@ def _make_member_fns(spec: twr.TowerSpec, rules):
 
 
 def _make_routed_member_fns(spec: twr.TowerSpec, rules):
-    """A member tower with ``moe`` blocks: the forward also gives each
-    block's expert choices and adds its rows per held expert to the
-    running counts ``stats`` on the device; the backward routes by the
-    choices its forward made."""
+    """A member tower with rematerialised decoder blocks (``mla``,
+    ``ffn``, ``moe``). The forward also gives what its backward starts
+    from, ``kept``: the input of every block after the first and each
+    ``moe`` block's expert choices (``routes``); and it adds its rows per
+    held expert to the running counts ``stats`` on the device. The
+    backward takes each block's VJP at the input its forward kept, routed
+    by the choices that forward made (``twr.vjp_kept``): a round runs the
+    forward once, each block's recomputation once and the backward's two
+    products, and never the whole tower's forward again."""
     @jax.jit
     def fwd(params, x, stats):
-        u, aux = twr.apply_routed(spec, params, x, rules=rules)
-        return u, aux["routes"], {
+        u, aux = twr.apply_kept(spec, params, x, rules=rules)
+        kept = {"inputs": aux["inputs"], "routes": aux["routes"]}
+        return u, kept, {
             "rows": tuple(a + b for a, b in zip(stats["rows"],
                                                 aux["rows"])),
             "dropped": stats["dropped"] + aux["dropped"]}
 
     @jax.jit
-    def bwd(params, x, du, lr, routes):
-        _, vjp = jax.vjp(lambda p: twr.apply_routed(
-            spec, p, x, routes=routes, rules=rules)[0], params)
-        (g,) = vjp(du)
+    def bwd(params, x, du, lr, kept):
+        g = twr.vjp_kept(spec, params, x, kept["inputs"], kept["routes"],
+                         du, rules=rules)
         return jax.tree.map(lambda p, gg: p - lr * gg, params, g)
 
     return fwd, bwd
@@ -254,17 +259,21 @@ class SplitNNProtocol(VFLProtocol):
             self._rules = twr.make_tower_rules(cfg.tower_shard)
             self.params = twr.shard_tower(self.params, self._spec,
                                           self._rules)
-            # a tower with moe blocks keeps its expert choices of the
-            # last forward (``routes``, the backward's) and its routed
-            # rows per held expert (``route_stats``), on the device
-            self._routed = bool(twr.routed_blocks(self._spec))
-            if self._routed:
+            # a tower with rematerialised decoder blocks keeps each
+            # round's block inputs and expert choices for the round's
+            # backward (its ctx), the last forward's choices
+            # (``routes``), its routed rows per held expert
+            # (``route_stats``, on the device) and the rounds whose
+            # backward started from what its forward kept
+            self._kept = bool(twr.remat_blocks(self._spec))
+            if self._kept:
                 self._fwd, self._bwd = _make_routed_member_fns(
                     self._spec, self._rules)
                 self._embed = jax.jit(functools.partial(
                     twr.apply, self._spec, rules=self._rules))
                 self.route_stats = route_stats(self._spec)
                 self.routes = None
+                self.kept_rounds = 0
             else:
                 self._fwd, self._bwd = _make_member_fns(self._spec,
                                                         self._rules)
@@ -329,16 +338,17 @@ class SplitNNProtocol(VFLProtocol):
 
     def member_stage_send(self, rows, step):
         """Bottom forward + activation isend; the batch slice (with the
-        expert choices, for a routed tower) is the ctx the deferred
-        backward stage reuses (its VJP must see the inputs and routing
-        this forward actually saw)."""
+        block inputs and expert choices the forward kept, for a decoder
+        tower) is the ctx the deferred backward stage reuses (its VJP
+        must see the inputs and routing this forward actually saw)."""
         with obs.span(self._sp_gather, step=step):
             (xb,) = _take((self.x,), rows)
         with obs.span(self._sp_step, step=step):
-            if self._routed:
-                u, self.routes, self.route_stats = self._fwd(
+            if self._kept:
+                u, kept, self.route_stats = self._fwd(
                     self.params, xb, self.route_stats)
-                xb = (xb, self.routes)
+                self.routes = kept["routes"]
+                xb = (xb, kept)
             else:
                 u = self._fwd(self.params, xb)
         if self.cfg.noise_sigma > 0:
@@ -363,10 +373,11 @@ class SplitNNProtocol(VFLProtocol):
         with obs.span(self._sp_h2d, step=step):
             du = jnp.asarray(du, jnp.float32)
         with obs.span(self._sp_step, step=step):
-            if self._routed:
-                xb, routes = xb
+            if self._kept:
+                xb, kept = xb
                 self.params = self._bwd(self.params, xb, du, self.lr,
-                                        routes)
+                                        kept)
+                self.kept_rounds += 1
             else:
                 self.params = self._bwd(self.params, xb, du, self.lr)
 
@@ -417,7 +428,12 @@ class SplitNNProtocol(VFLProtocol):
             return {"top": jax.tree.map(np.asarray, self.top),
                     "bottom": jax.tree.map(np.asarray, self.bottom),
                     "order": self.order}
-        return {"params": jax.tree.map(np.asarray, self.params)}
+        out = {"params": jax.tree.map(np.asarray, self.params)}
+        if self._kept:
+            out.update(route_stats=jax.tree.map(np.asarray,
+                                                self.route_stats),
+                       kept_rounds=self.kept_rounds)
+        return out
 
     def _ef_residuals(self) -> Dict:
         # error feedback now lives on the typed channel (schema-level

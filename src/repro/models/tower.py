@@ -46,7 +46,10 @@ Decoder blocks over token ids (DeepSeek-V2 style, ``docs/models.md``):
 ``mla``/``ffn``/``moe`` recompute their forward in the backward pass,
 keeping only each block's input (at 16 users x 512 tokens six
 DeepSeek-V2-Lite layers' activations would otherwise take ~10 GB of a
-16 GB chip).
+16 GB chip). ``apply_kept`` returns those inputs beside the output, and
+``vjp_kept`` takes the backward from them block by block, so that a
+training step need not run the tower's forward a second time to find
+them.
 
 Blocks are written either as dicts or as compact strings
 ``"kind:key=val,key=val"`` with ``|``-separated integer tuples::
@@ -573,19 +576,45 @@ def apply(spec: TowerSpec, params: Sequence[Any], x,
     return _run(spec, params, x, rules)[0]
 
 
-def apply_routed(spec: TowerSpec, params: Sequence[Any], x, routes=None,
-                 rules=None):
-    """Forward pass of a tower with ``moe`` blocks: ``(y, aux)``.
-
-    ``aux["routes"]``: each ``moe`` block's (tokens, top_k) int32 expert
-    choices; ``aux["rows"]``: each one's (held,) rows routed to its held
-    experts; ``aux["dropped"]``: routed rows left uncomputed (0). Given
-    ``routes`` (a previous call's), the blocks route by them instead of
-    choosing again: a backward pass then differentiates exactly the
-    forward that was sent."""
-    y, sels, rows, dropped = _run(spec, params, x, rules, routes)
+def apply_kept(spec: TowerSpec, params: Sequence[Any], x, rules=None):
+    """Forward pass that keeps what :func:`vjp_kept` starts from:
+    ``(y, aux)``. ``aux["inputs"]``: the input of every block after the
+    first, as this forward computed it; ``aux["routes"]``: each ``moe``
+    block's (tokens, top_k) int32 expert choices; ``aux["rows"]``: each
+    one's (held,) rows routed to its held experts; ``aux["dropped"]``:
+    routed rows left uncomputed (0)."""
+    inputs: List[jax.Array] = []
+    y, sels, rows, dropped = _run(spec, params, x, rules, kept=inputs)
     return y, {"routes": tuple(sels), "rows": tuple(rows),
-               "dropped": sum(dropped, jnp.zeros((), jnp.int32))}
+               "dropped": sum(dropped, jnp.zeros((), jnp.int32)),
+               "inputs": tuple(inputs)}
+
+
+def vjp_kept(spec: TowerSpec, params: Sequence[Any], x, inputs, routes,
+             g, rules=None) -> List[Any]:
+    """The gradient of ``<apply(spec, params, x), g>`` in ``params``,
+    from a forward's kept block ``inputs`` and ``routes``
+    (:func:`apply_kept`'s): block by block from the head down, each
+    block's VJP taken at its kept input, its input's cotangent handed to
+    the block below. A ``moe`` block routes by its kept choices, so that
+    the gradient is exactly the sent forward's. No forward of the whole
+    tower runs here; a rematerialised block recomputes its own forward
+    once, inside its VJP."""
+    grads: List[Any] = [None] * len(spec.blocks)
+    routes = list(routes)
+    for i in reversed(range(len(spec.blocks))):
+        b = spec.blocks[i]
+        r = routes.pop() if b["kind"] == "moe" else None
+        block = partial(_apply_block, b, rules=rules, route=r)
+        if i:
+            _, vjp, _ = jax.vjp(block, params[i], inputs[i - 1],
+                                has_aux=True)
+            grads[i], g = vjp(g)
+        else:
+            _, vjp, _ = jax.vjp(partial(block, x=x), params[0],
+                                has_aux=True)
+            (grads[0],) = vjp(g)
+    return grads
 
 
 def routed_blocks(spec: TowerSpec) -> List[Dict[str, Any]]:
@@ -593,22 +622,44 @@ def routed_blocks(spec: TowerSpec) -> List[Dict[str, Any]]:
     return [b for b in spec.blocks if b["kind"] == "moe"]
 
 
-def _run(spec, params, x, rules=None, routes=None):
+# blocks whose forward the backward pass recomputes (``jax.checkpoint``)
+REMAT_KINDS = ("mla", "ffn", "moe")
+
+
+def remat_blocks(spec: TowerSpec) -> List[Dict[str, Any]]:
+    """The tower's rematerialised blocks, in order."""
+    return [b for b in spec.blocks if b["kind"] in REMAT_KINDS]
+
+
+def _run(spec, params, x, rules=None, kept=None):
+    """The blocks in order: ``(y, routes, rows, dropped)``, the last three
+    per ``moe`` block. ``kept``, a list, receives the input of every block
+    after the first."""
     sels, rows, dropped = [], [], []
-    for b, p in zip(spec.blocks, params):
-        if b["kind"] == "moe":
-            r = None if routes is None else routes[len(sels)]
-            x, aux = _apply_moe(b, p, x, r)
+    for i, (b, p) in enumerate(zip(spec.blocks, params)):
+        if kept is not None and i:
+            kept.append(x)
+        x, aux = _apply_block(b, p, x, rules)
+        if aux is not None:
             sels.append(aux["routes"])
             rows.append(aux["rows"])
             dropped.append(aux["dropped"])
-        else:
-            x = _BLOCK_APPLY[b["kind"]](b, p, x, rules)
-        if x.ndim == 3:
-            x = _constrain(x, ("batch", None, None), rules)
-        else:
-            x = _constrain(x, ("batch", "mlp"), rules)
     return x, sels, rows, dropped
+
+
+def _apply_block(b, p, x, rules=None, route=None):
+    """One block, then its output's sharding: ``(y, aux)``, ``aux`` a
+    ``moe`` block's routing (None for any other kind)."""
+    aux = None
+    if b["kind"] == "moe":
+        x, aux = _apply_moe(b, p, x, route)
+    else:
+        x = _BLOCK_APPLY[b["kind"]](b, p, x, rules)
+    if x.ndim == 3:
+        x = _constrain(x, ("batch", None, None), rules)
+    else:
+        x = _constrain(x, ("batch", "mlp"), rules)
+    return x, aux
 
 
 def _shard(rules, logical, shape):

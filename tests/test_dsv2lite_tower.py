@@ -115,8 +115,8 @@ def test_member_tower_matches_reference_forward_and_gradient():
     w = model.init(jax.random.key(5))["members"][0]
     spec = twr.resolve(TOWER, SEQ, 8)
     tokens = _tokens()
-    routed = jax.jit(functools.partial(twr.apply_routed, spec))
-    u, aux = routed(w, tokens)
+    kept = jax.jit(functools.partial(twr.apply_kept, spec))
+    u, aux = kept(w, tokens)
     routes = aux["routes"]
     assert [r.shape for r in routes] == [(BATCH * SEQ, 3)]
     np.testing.assert_array_equal(
@@ -133,26 +133,28 @@ def test_member_tower_matches_reference_forward_and_gradient():
         return R.mlp_apply(model.head, [p[-1]], jnp.mean(h, 1), "highest")
     np.testing.assert_allclose(u, jax.jit(ref)(w), rtol=2e-5, atol=2e-6)
     c = jax.random.normal(jax.random.key(3), u.shape)
-    gp = jax.jit(jax.grad(lambda p: jnp.sum(
-        routed(p, tokens, routes=routes)[0] * c)))(w)
+    gp = jax.jit(functools.partial(twr.vjp_kept, spec))(
+        w, tokens, aux["inputs"], routes, c)
     gr = jax.jit(jax.grad(lambda p: jnp.sum(ref(p) * c)))(w)
     _assert_trees_close(gp, gr, 2e-5)
 
 
 def test_routed_backward_follows_the_forward_routes():
-    """Handed the forward's choices, the tower computes the same output
-    as choosing again; handed other choices, another one."""
-    model = _model()
-    w = model.init(jax.random.key(5))["members"][0]
+    """Handed the forward's choices, the backward gives the gradient of
+    the tower that chooses again; handed other choices, another one."""
+    w = _model().init(jax.random.key(5))["members"][0]
     spec = twr.resolve(TOWER, SEQ, 8)
     tokens = _tokens()
-    routed = jax.jit(functools.partial(twr.apply_routed, spec))
-    u, aux = routed(w, tokens)
-    again, _ = routed(w, tokens, routes=aux["routes"])
-    np.testing.assert_array_equal(u, again)
-    moved, _ = routed(w, tokens, routes=tuple(
-        (r + 1) % 16 for r in aux["routes"]))
-    assert not np.allclose(u, moved)
+    u, aux = jax.jit(functools.partial(twr.apply_kept, spec))(w, tokens)
+    c = jax.random.normal(jax.random.key(3), u.shape)
+    grad = jax.jit(functools.partial(twr.vjp_kept, spec))
+    again = jax.jit(jax.grad(lambda p: jnp.sum(twr.apply(spec, p, tokens)
+                                               * c)))(w)
+    _assert_trees_close(grad(w, tokens, aux["inputs"], aux["routes"], c),
+                        again, 1e-6)
+    moved = grad(w, tokens, aux["inputs"],
+                 tuple((r + 1) % 16 for r in aux["routes"]), c)
+    assert not np.allclose(moved[4]["w_gate"], again[4]["w_gate"])
 
 
 # ---------------------------------------------------------------------------
@@ -288,16 +290,156 @@ def test_routing_counts_and_choices_reach_the_member():
     fwd, bwd = split_nn._make_routed_member_fns(spec, None)
     stats = split_nn.route_stats(spec)
     tokens = _tokens()
-    u, routes, stats = fwd(w, tokens, stats)
-    u, routes, stats = fwd(w, tokens, stats)
+    u, kept, stats = fwd(w, tokens, stats)
+    u, kept, stats = fwd(w, tokens, stats)
     rows = [np.asarray(r) for r in stats["rows"]]
     assert [r.shape for r in rows] == [(4,)]
-    for r, sel in zip(rows, routes):
+    for r, sel in zip(rows, kept["routes"]):
         held = (np.asarray(sel) >= 4) & (np.asarray(sel) < 8)
         assert int(r.sum()) == 2 * int(held.sum())
     assert int(stats["dropped"]) == 0
-    new = bwd(w, tokens, jnp.ones_like(u), jnp.float32(0.1), routes)
+    new = bwd(w, tokens, jnp.ones_like(u), jnp.float32(0.1), kept)
     assert jax.tree.structure(new) == jax.tree.structure(w)
+
+
+# ---------------------------------------------------------------------------
+# the member's backward from the block inputs its forward kept
+# ---------------------------------------------------------------------------
+
+
+def _primitives(jaxpr, counts):
+    """Each primitive's count in ``jaxpr`` and its sub-jaxprs."""
+    from jax.extend import core as jcore
+    for e in jaxpr.eqns:
+        counts[e.primitive.name] = counts.get(e.primitive.name, 0) + 1
+        for v in e.params.values():
+            for j in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(j, jcore.ClosedJaxpr):
+                    _primitives(j.jaxpr, counts)
+                elif isinstance(j, jcore.Jaxpr):
+                    _primitives(j, counts)
+    return counts
+
+
+def _live_primitives(fn, *args):
+    """Primitive counts of what ``jax.jit`` lowers for ``fn(*args)``: the
+    traced jaxpr with its dead equations removed, as jit removes them
+    (each block's VJP leaves the block's own output unused)."""
+    from jax._src.interpreters import partial_eval as pe
+    jaxpr = fn.trace(*args).jaxpr.jaxpr
+    live, _ = pe.dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))
+    return _primitives(live, {})
+
+
+def test_member_backward_runs_no_whole_tower_forward():
+    """The backward is each block's VJP at the input its forward kept: a
+    routed row goes through 3 grouped products in the forward and 9 in
+    the backward (the block's recomputation and the two gradients of
+    each), and every product of the forward at most three times there.
+    A forward of the whole tower inside the backward would make both 12
+    and about four."""
+    from repro.core.protocols import split_nn
+    w = _model().init(jax.random.key(5))["members"][0]
+    spec = twr.resolve(TOWER, SEQ, 8)
+    fwd, bwd = split_nn._make_routed_member_fns(spec, None)
+    stats = split_nn.route_stats(spec)
+    tokens = _tokens()
+    u, kept, _ = fwd(w, tokens, stats)
+    f = _live_primitives(fwd, w, tokens, stats)
+    b = _live_primitives(bwd, w, tokens, jnp.ones_like(u), jnp.float32(0.1),
+                         kept)
+    moe_blocks = len(twr.routed_blocks(spec))
+    assert f["ragged_dot_general"] == 3 * moe_blocks
+    assert b["ragged_dot_general"] == 9 * moe_blocks
+    assert f["dot_general"] < b["dot_general"] <= 3 * f["dot_general"]
+    assert "top_k" not in b                    # routed by the kept choices
+
+
+def test_member_forward_keeps_block_inputs_and_routes_only():
+    """What the forward hands its backward is the input of every block
+    after the first and each moe block's choices: no more bytes than
+    those, and no leaf shaped like a weight."""
+    from repro.core.protocols import split_nn
+    w = _model().init(jax.random.key(5))["members"][0]
+    spec = twr.resolve(TOWER, SEQ, 8)
+    fwd, _ = split_nn._make_routed_member_fns(spec, None)
+    _, kept, _ = fwd(w, _tokens(), split_nn.route_stats(spec))
+    leaves = jax.tree.leaves(kept)
+    boundaries = (len(spec.blocks) - 1) * BATCH * SEQ * DIM * 4
+    routes = len(twr.routed_blocks(spec)) * BATCH * SEQ * 3 * 4
+    assert sum(x.nbytes for x in leaves) == boundaries + routes
+    assert [x.shape for x in kept["inputs"]] \
+        == [(BATCH, SEQ, DIM)] * (len(spec.blocks) - 1)
+    weights = {x.shape for x in jax.tree.leaves(w)}
+    assert not weights & {x.shape for x in leaves}
+
+
+def _whole_tower_member(make):
+    """The member with a backward through the whole tower from the token
+    ids, routed by its forward's choices, through the activations its
+    forward computed. At ``pipeline_depth`` 2 a round's backward comes
+    after a later round has moved the weights: each block is then
+    differentiated at the weights of now, at the input its forward saw
+    (from the forward's weights, which this member keeps and the
+    program does not)."""
+    def member(spec, rules):
+        fwd, _ = make(spec, rules)
+
+        def keep_weights(params, x, stats):
+            u, kept, stats = fwd(params, x, stats)
+            return u, {"routes": kept["routes"], "weights": params}, stats
+
+        @jax.jit
+        def bwd(params, x, du, lr, kept):
+            def tower(p):
+                h, routes = x, list(kept["routes"])
+                for b, now, then in zip(spec.blocks, p, kept["weights"]):
+                    r = routes.pop(0) if b["kind"] == "moe" else None
+                    # the value of the forward's, the derivative of now's
+                    y = twr._apply_block(b, now, h, rules, r)[0]
+                    h = jax.lax.stop_gradient(
+                        twr._apply_block(b, then, h, rules, r)[0]) \
+                        + (y - jax.lax.stop_gradient(y))
+                return h
+            _, vjp = jax.vjp(tower, params)
+            (g,) = vjp(du)
+            return jax.tree.map(lambda p, gg: p - lr * gg, params, g)
+        return keep_weights, bwd
+    return member
+
+
+def _three_rounds(depth):
+    """Three rounds of the tiny token tower through ``VFLJob``: the
+    member's result and the master's losses."""
+    job = _token_job(48, epochs=1, depth=depth)
+    try:
+        losses = [h["loss"] for h in job.fit()["history"]]
+    finally:
+        res = job.shutdown()
+    return res["member0"], losses
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_member_step_from_kept_inputs_matches_the_whole_tower_backward(
+        monkeypatch, depth):
+    """Over three rounds through ``VFLJob``, the member's step from its
+    kept block inputs updates the weights as a backward through the
+    whole tower from the token ids does, within a few float32 roundings
+    of each leaf, and counts every round it ran."""
+    from repro.core.protocols import split_nn
+    got, losses = _three_rounds(depth)
+    assert got["kept_rounds"] == len(losses) == 3
+    monkeypatch.setattr(split_nn, "_make_routed_member_fns",
+                        _whole_tower_member(split_nn._make_routed_member_fns))
+    want, want_losses = _three_rounds(depth)
+    np.testing.assert_allclose(losses, want_losses, rtol=4e-7)
+    eps = float(np.finfo(np.float32).eps)
+    for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path(got["params"]),
+            jax.tree.leaves(want["params"])):
+        gap = float(np.max(np.abs(a - b)))
+        assert gap <= 4 * eps * float(np.max(np.abs(b))), (
+            jax.tree_util.keystr(path), gap)
 
 
 # ---------------------------------------------------------------------------
@@ -379,21 +521,28 @@ def test_benchmark_configuration_has_the_models_widths():
 # ---------------------------------------------------------------------------
 
 
-def test_token_tower_trains_through_vfljob():
+def _token_job(rows, epochs, depth=1):
+    """A tiny token tower's job in thread mode: ``rows`` member rows
+    among ``rows + 32`` users, 16 a round."""
     from repro.core.party import VFLJob
     from repro.core.protocols.base import MasterData, MemberData, VFLConfig
     rng = np.random.default_rng(0)
-    n = 96
+    n = rows + 32
     ids = [f"u{i:04d}" for i in range(n)]
     y = (rng.random((n, 3)) < 0.3).astype(np.float32)
     xm = rng.normal(size=(n, 6)).astype(np.float32)
-    tokens = rng.integers(0, VOCAB, (64, SEQ)).astype(np.int32)
-    cfg = VFLConfig(protocol="split_nn", epochs=2, batch_size=16, lr=0.1,
-                    seed=0, use_psi=False, embedding_dim=8, tower=TOWER,
-                    master_tower=("mlp:hidden=16",),
-                    top_tower=("mlp:hidden=8,final_act=0",))
-    with VFLJob(cfg, MasterData(ids, y, xm),
-                [MemberData(ids[16:80], tokens)], mode="thread") as job:
+    tokens = rng.integers(0, VOCAB, (rows, SEQ)).astype(np.int32)
+    cfg = VFLConfig(protocol="split_nn", epochs=epochs, batch_size=16,
+                    lr=0.1, seed=0, use_psi=False, embedding_dim=8,
+                    tower=TOWER, master_tower=("mlp:hidden=16",),
+                    top_tower=("mlp:hidden=8,final_act=0",),
+                    pipeline_depth=depth)
+    return VFLJob(cfg, MasterData(ids, y, xm),
+                  [MemberData(ids[16:16 + rows], tokens)], mode="thread")
+
+
+def test_token_tower_trains_through_vfljob():
+    with _token_job(64, epochs=2) as job:
         res = job.fit()
         scores = np.asarray(job.predict())
     losses = [h["loss"] for h in res["history"]]
