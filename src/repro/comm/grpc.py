@@ -43,6 +43,7 @@ Scope (documented in docs/transports.md, internals in DESIGN.md §8):
 """
 from __future__ import annotations
 
+import select
 import socket
 import struct
 import threading
@@ -53,7 +54,7 @@ from repro import obs
 from repro.comm import codec
 from repro.comm.base import Message
 from repro.comm.sock import (_MidFrameClose, _TcpCommunicator,
-                             _recv_exact, local_addresses)
+                             _recv_exact, _shut, local_addresses)
 
 __all__ = ["GrpcCommunicator", "local_addresses"]
 
@@ -254,6 +255,26 @@ class _FlowState:
             self.cv.notify_all()
 
 
+class _SerialReads:
+    """An outbound connection as its reader thread sees it, while the
+    sender thread writes through it: wait for bytes with the write lock
+    free, read them holding it. Two threads are then never inside one
+    SSL object at once, which OpenSSL does not allow: a read racing a
+    write there fails, or loses, a fresh connection's first message."""
+
+    def __init__(self, conn: socket.socket, lock: threading.Lock):
+        self._conn, self._lock = conn, lock
+        self._poll = select.poll()
+        self._poll.register(conn, select.POLLIN)
+        self._pending = getattr(conn, "pending", lambda: 0)
+
+    def recv(self, n: int) -> bytes:
+        if not self._pending():          # bytes OpenSSL already holds
+            self._poll.poll()
+        with self._lock:
+            return self._conn.recv(n)
+
+
 class GrpcCommunicator(_TcpCommunicator):
     """gRPC-framed transport; a drop-in peer of ``SocketCommunicator``.
 
@@ -307,10 +328,13 @@ class GrpcCommunicator(_TcpCommunicator):
         """Consume the server's control frames on an outbound
         connection: SETTINGS (initial window size; acked), WINDOW_UPDATE
         (credit). Exits — releasing any window-blocked sender — when the
-        connection dies."""
+        connection dies, and closes the connection, whose reads are
+        this thread's alone (see ``_drop_out``)."""
+        lock = self._wl[conn]
+        reads = _SerialReads(conn, lock)
         try:
             while True:
-                ftype, flags, stream, body = _read_frame(conn)
+                ftype, flags, stream, body = _read_frame(reads)
                 if ftype == FT_SETTINGS:
                     if flags & FLAG_ACK:
                         continue
@@ -318,11 +342,8 @@ class GrpcCommunicator(_TcpCommunicator):
                         SETTINGS_INITIAL_WINDOW_SIZE)
                     if iw is not None:
                         fc.apply_settings(iw)
-                    lock = self._wl.get(conn)
-                    if lock is not None:
-                        with lock:
-                            conn.sendall(
-                                _frame(FT_SETTINGS, FLAG_ACK, 0, b""))
+                    with lock:
+                        conn.sendall(_frame(FT_SETTINGS, FLAG_ACK, 0, b""))
                 elif ftype == FT_WINDOW_UPDATE:
                     inc = int.from_bytes(body[:4], "big") & 0x7FFFFFFF
                     fc.window_update(stream, inc)
@@ -332,7 +353,14 @@ class GrpcCommunicator(_TcpCommunicator):
         finally:
             fc.close()
             self._fc.pop(conn, None)
-            self._wl.pop(conn, None)
+            with lock:           # no write is mid-way through OpenSSL
+                self._wl.pop(conn, None)
+                conn.close()
+
+    def _drop_out(self, conn: socket.socket) -> None:
+        """End an outbound connection: only shut it down, and leave the
+        close to its reader thread, which wakes to EOF."""
+        _shut(conn)
 
     def _write_frames(self, recipient: str, *bufs: bytes) -> None:
         conn = self._conn_to(recipient)
@@ -377,10 +405,7 @@ class GrpcCommunicator(_TcpCommunicator):
                     # a stalled window is a dead link: drop the cached
                     # conn so no later write corrupts peer framing
                     self._out.pop(msg.recipient, None)
-                    try:
-                        conn.close()
-                    except OSError:
-                        pass
+                    self._drop_out(conn)
                     raise
                 # small messages coalesce HEADERS+DATA into one sendall
                 # (one packet under NODELAY), mirroring the socket

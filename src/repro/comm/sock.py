@@ -44,6 +44,21 @@ class _MidFrameClose(ConnectionError):
     """The peer closed with a partially-delivered read outstanding."""
 
 
+def _shut(conn: socket.socket) -> None:
+    """Shut a connection down without closing it. A thread blocked
+    reading it wakes to EOF while its descriptor stays open, and that
+    thread closes it. Closing it under a reader instead frees the
+    descriptor while OpenSSL, mid-read, still holds its number: the
+    read it retries, or the alert it sends, then lands on whatever file
+    reuses the number next, such as a multiprocessing pipe. The plain
+    socket's shutdown, not ``SSLSocket.shutdown``, which would drop the
+    SSL object under the reader's feet."""
+    try:
+        socket.socket.shutdown(conn, socket.SHUT_RDWR)
+    except OSError:
+        pass
+
+
 def _recv_exact(conn: socket.socket, n: int) -> bytes:
     chunks = []
     got = 0
@@ -250,11 +265,17 @@ class _TcpCommunicator(PartyCommunicator):
                 conn.sendall(b)
         except BaseException:
             self._out.pop(recipient, None)
-            try:
-                conn.close()
-            except OSError:
-                pass
+            self._drop_out(conn)
             raise
+
+    def _drop_out(self, conn: socket.socket) -> None:
+        """End an outbound connection. This framing reads nothing on
+        it, so the caller is its only user and may close it at once."""
+        _shut(conn)
+        try:
+            conn.close()
+        except OSError:
+            pass
 
     # -- receive side --------------------------------------------------------
     def _recv_any(self, frm: str, tags: Sequence[str],
@@ -307,10 +328,7 @@ class _TcpCommunicator(PartyCommunicator):
                 self._suspect = None
         out = self._out.pop(peer, None)
         if out is not None:
-            try:
-                out.close()
-            except OSError:
-                pass
+            self._drop_out(out)
         with self._cv:
             self._down.discard(peer)
             for key in list(self._pending):
@@ -334,18 +352,14 @@ class _TcpCommunicator(PartyCommunicator):
         except OSError:
             pass
         self._listener.join(timeout=5)
-        for c in self._out.values():
-            try:
-                c.close()
-            except OSError:
-                pass
+        for c in list(self._out.values()):
+            self._drop_out(c)
+        # an inbound connection's serve thread closes it once the
+        # shutdown has woken its read (see _shut)
         with self._in_lock:
             pending_in = list(self._in)
         for c in pending_in:
-            try:
-                c.close()
-            except OSError:
-                pass
+            _shut(c)
 
 
 class SocketCommunicator(_TcpCommunicator):

@@ -34,6 +34,8 @@ import queue
 import threading
 import time
 import traceback
+from multiprocessing.connection import Connection
+from multiprocessing.connection import wait as wait_ready
 from typing import Any, Dict, List, Optional, Sequence
 
 from repro.comm.base import CommCfg, PartyCommunicator
@@ -185,15 +187,15 @@ class Arbiter(VFLAgent):
 # ---------------------------------------------------------------------------
 
 
-def _drive_master(driver: Driver, cmd_q, res_q) -> Dict[str, Any]:
+def _drive_master(driver: Driver, cmd_q, reply) -> Dict[str, Any]:
     """Command loop for the master agent: the owning VFLJob feeds
-    (phase, kwargs) pairs; each reply is ("ok", payload) or
+    (phase, kwargs) pairs; each ``reply`` is ("ok", payload) or
     ("error", wrapped-exception)."""
     while True:
         cmd, kw = cmd_q.get()
         if cmd == "shutdown":
             driver.shutdown_world()
-            res_q.put(("ok", None))
+            reply(("ok", None))
             break
         try:
             if cmd == "fit":
@@ -211,15 +213,15 @@ def _drive_master(driver: Driver, cmd_q, res_q) -> Dict[str, Any]:
             else:
                 raise ValueError(f"unknown job command {cmd!r}")
         except BaseException as e:
-            res_q.put(("error", _wrap_exc(e)))
+            reply(("error", _wrap_exc(e)))
             raise
-        res_q.put(("ok", r))
+        reply(("ok", r))
     return driver.result()
 
 
 def _agent_entry(role: str, comm: PartyCommunicator, cfg: VFLConfig,
                  data, out: Dict[str, Any], callbacks=None,
-                 resume_dir=None, cmd_q=None, res_q=None) -> None:
+                 resume_dir=None, cmd_q=None, reply=None) -> None:
     proto_cls = resolve_protocol(cfg.protocol)
     proto = proto_cls(cfg, TypedChannel(comm, compress=cfg.compress),
                       role)
@@ -228,13 +230,13 @@ def _agent_entry(role: str, comm: PartyCommunicator, cfg: VFLConfig,
     try:
         driver.prepare(data)
         if role == "master":
-            out[role] = _drive_master(driver, cmd_q, res_q)
+            out[role] = _drive_master(driver, cmd_q, reply)
         else:
             out[role] = driver.follow()
     except BaseException as e:   # propagate to the runner
         out[role] = {"error": e}
-        if role == "master" and res_q is not None:
-            res_q.put(("error", _wrap_exc(e)))
+        if role == "master" and reply is not None:
+            reply(("error", _wrap_exc(e)))
         raise
     finally:
         try:
@@ -243,8 +245,8 @@ def _agent_entry(role: str, comm: PartyCommunicator, cfg: VFLConfig,
             comm.close()
 
 
-def _mp_entry(role, transport, world, cfg, data, q, callbacks=None,
-              resume_dir=None, cmd_q=None, res_q=None,
+def _mp_entry(role, transport, world, cfg, data, done, callbacks=None,
+              resume_dir=None, cmd_q=None, reply=None,
               comm_cfg=None):
     # module-level for picklability (spawn). ``transport`` selects the
     # wire: ("bus", mp queue boxes), ("sock", address map) or
@@ -271,14 +273,13 @@ def _mp_entry(role, transport, world, cfg, data, q, callbacks=None,
     out: Dict[str, Any] = {}
     try:
         _agent_entry(role, comm, cfg, data, out, callbacks, resume_dir,
-                     cmd_q, res_q)
+                     cmd_q, reply.send if reply is not None else None)
     except BaseException as e:
-        # the error must reach the parent's queue BEFORE this process
-        # dies — otherwise run_vfl blocks its full timeout and reports
-        # queue.Empty instead of the real traceback
-        q.put((role, {"error": _wrap_exc(e)}))
+        # the error must reach the parent BEFORE this process dies, so
+        # the job raises the real traceback, not just an exit code
+        done.send({"error": _wrap_exc(e)})
         raise
-    q.put((role, out[role]))
+    done.send(out[role])
 
 
 # ---------------------------------------------------------------------------
@@ -365,7 +366,10 @@ class VFLJob:
         self._closed = False
         self._threads: List[threading.Thread] = []
         self._procs: Dict[str, mp.Process] = {}
-        self._q = None                      # process-mode exit results
+        # process modes: the master's reply pipe and each agent's exit
+        # pipe, read ends only (see _poll_procs)
+        self._reply: Optional[Connection] = None
+        self._exits: Dict[str, Connection] = {}
 
         if mode in ("thread", "socket", "grpc"):
             self._cmd_q: Any = queue.Queue()
@@ -390,7 +394,7 @@ class VFLJob:
                     args=(w, comms[w], cfg, datas[w], self._results,
                           list(callbacks), resume_dir,
                           self._cmd_q if is_m else None,
-                          self._res_q if is_m else None),
+                          self._res_q.put if is_m else None),
                     daemon=True)
                 self._threads.append(t)
                 t.start()
@@ -419,26 +423,31 @@ class VFLJob:
             else:
                 # one OS process per agent over real TCP — the paper's
                 # distributed deployment on one host; control replies
-                # still ride mp queues
+                # still ride mp pipes
                 kind = "sock" if mode == "socket_proc" else "grpc"
                 transport = (kind, local_addresses(self.world))
-            self._q = ctx.Queue()
             self._cmd_q = ctx.Queue()
-            self._res_q = ctx.Queue()
+            # replies and exit results ride one-way pipes whose write
+            # end only the agent holds, so an agent that dies, even
+            # mid-message, leaves EOF behind and never a read that blocks
+            self._reply, reply_w = ctx.Pipe(duplex=False)
             for w in self.world:
                 is_m = w == "master"
+                self._exits[w], done_w = ctx.Pipe(duplex=False)
                 p = ctx.Process(
                     target=_mp_entry,
                     args=(w, transport, self.world, cfg, datas[w],
-                          self._q, list(callbacks), resume_dir,
+                          done_w, list(callbacks), resume_dir,
                           self._cmd_q if is_m else None,
-                          self._res_q if is_m else None,
+                          reply_w if is_m else None,
                           _cfg_for(w)))
                 # daemonized: an abandoned job (no shutdown) must not
                 # block interpreter exit on multiprocessing's atexit join
                 p.daemon = True
                 self._procs[w] = p
                 p.start()
+                done_w.close()
+            reply_w.close()
         else:
             raise ValueError(f"unknown mode {mode!r}")
 
@@ -555,24 +564,52 @@ class VFLJob:
         instead of stalling the job until the comm timeout."""
         deadline = time.monotonic() + timeout
         while True:
+            if self._procs:
+                reply = self._poll_procs(0.2)
+                if reply is not None:
+                    return reply
+            else:
+                try:
+                    return self._res_q.get(timeout=0.2)
+                except queue.Empty:
+                    pass
+            err = self._peek_agent_error()
+            if err is not None:
+                self._fail(*err)
+            if time.monotonic() > deadline:
+                self._abort()
+                raise TimeoutError("master agent did not reply")
+
+    def _poll_procs(self, timeout: float):
+        """Process modes: wait up to ``timeout`` on the master's reply
+        pipe and on each agent's exit pipe and process, take in the
+        exit results that came, and return the reply if one did. The
+        parent holds no write end of these pipes, so a dead writer
+        gives EOF here, never a read that blocks."""
+        watch: Dict[Any, Optional[str]] = {}
+        if self._reply is not None:
+            watch[self._reply] = None
+        for role, conn in self._exits.items():
+            watch[conn] = watch[self._procs[role].sentinel] = role
+        ready = wait_ready(list(watch), timeout)
+        for role in {watch[r] for r in ready} - {None}:
+            conn = self._exits.pop(role)
             try:
-                return self._res_q.get(timeout=0.2)
-            except queue.Empty:
-                err = self._peek_agent_error()
-                if err is not None:
-                    self._fail(*err)
-                if time.monotonic() > deadline:
-                    self._abort()
-                    raise TimeoutError("master agent did not reply")
+                if conn.poll():
+                    self._results[role] = conn.recv()
+            except (EOFError, OSError):
+                pass      # ended without a whole result: see exit code
+            conn.close()
+        if self._reply is not None and self._reply in ready:
+            try:
+                return self._reply.recv()
+            except (EOFError, OSError):
+                # the master is gone; its exit result or code says why
+                self._reply.close()
+                self._reply = None
+        return None
 
     def _peek_agent_error(self):
-        if self._q is not None:           # process mode: drain exits
-            while True:
-                try:
-                    role, res = self._q.get_nowait()
-                except queue.Empty:
-                    break
-                self._results[role] = res
         for role, res in list(self._results.items()):
             if isinstance(res, dict) and isinstance(res.get("error"),
                                                     BaseException):
@@ -603,15 +640,8 @@ class VFLJob:
     def _finish(self, timeout: float) -> Dict[str, Any]:
         if self._procs:
             deadline = time.monotonic() + timeout
-            while len(self._results) < len(self.world) \
-                    and time.monotonic() < deadline:
-                try:
-                    role, res = self._q.get(timeout=1.0)
-                    self._results[role] = res
-                except queue.Empty:
-                    if not any(p.is_alive()
-                               for p in self._procs.values()):
-                        break
+            while self._exits and time.monotonic() < deadline:
+                self._poll_procs(deadline - time.monotonic())
             for p in self._procs.values():
                 p.join(timeout=60)
         else:

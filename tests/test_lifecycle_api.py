@@ -5,13 +5,17 @@ predict round-trips without retraining, agent failures propagate with
 real tracebacks, and tail batches are no longer silently dropped."""
 import dataclasses
 import json
+import os
 import pathlib
+import re
+import threading
 import time
 
 import numpy as np
 import pytest
 
 from repro.comm import schema
+from repro.comm.base import CommCfg
 from repro.comm.local import ThreadBus
 from repro.comm.schema import Field, SchemaError, TypedChannel
 from repro.core.party import VFLJob, run_vfl
@@ -415,6 +419,63 @@ def test_agent_failure_propagates_fast(mode):
         run_vfl(cfg, master, members, mode=mode)
     assert time.monotonic() - t0 < 120        # far below the 600s hang
     assert "deliberate member failure" in str(ei.value.__cause__)
+
+
+class ExitingMemberProtocol(LinRegProtocol):
+    """A member whose process ends in setup with nothing posted."""
+    name = "exiting_member"
+
+    def setup(self):
+        if self.is_member:
+            os._exit(7)
+        super().setup()
+
+
+class _DiesWhilePosted(RuntimeError):
+    """An error of 1 MiB of text, more than a pipe holds, whose process
+    ends 50 ms after the text is first asked for: that is, part way
+    through posting it to the job."""
+
+    def __str__(self):
+        threading.Timer(0.05, os._exit, (7,)).start()
+        return "x" * (1 << 20)
+
+
+class DyingWhilePostingMemberProtocol(LinRegProtocol):
+    name = "dying_while_posting_member"
+
+    def setup(self):
+        if self.is_member:
+            raise _DiesWhilePosted()
+        super().setup()
+
+
+@pytest.mark.parametrize("proto", ["ExitingMemberProtocol",
+                                   "DyingWhilePostingMemberProtocol"])
+def test_dead_agent_ends_the_wait_attributed(proto):
+    """An agent process that ends without posting, or part way through
+    posting, leaves the job's pipe at EOF: ``fit`` raises an attributed
+    error within seconds, where a channel whose write end the job also
+    held left its read blocked on the missing rest for good."""
+    cfg, master, members = _linreg_case()
+    cfg = dataclasses.replace(cfg, protocol=f"test_lifecycle_api:{proto}")
+    out = {}
+
+    def run():
+        try:
+            run_vfl(cfg, master, members, mode="process",
+                    comm_cfg=CommCfg(timeout=10.0))
+        except BaseException as e:
+            out["err"] = e
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(timeout=60)       # the comm timeout and the agents' spawn
+    assert not t.is_alive(), "the job still waits on its dead member"
+    err = out["err"]
+    assert re.fullmatch(r"agent member[01] failed", str(err)), err
+    why = str(err.__cause__)[:2000]
+    assert "exit code 7" in why or "_DiesWhilePosted" in why, why
 
 
 # ---------------------------------------------------------------------------

@@ -48,7 +48,8 @@ def _mesh(n_members):
 def test_masks_cancel_bit_exact(n_members, rnd, rows, cols):
     """Sum of all members' round-``rnd`` masks is exactly 0.0 — and in
     reversed order too, because grid values make fp32 addition exact."""
-    masks = [m.mask(rnd, (rows, cols)) for m in _mesh(n_members)]
+    maskers = _mesh(n_members)
+    masks = [m.mask(rnd, (rows, cols)) for m in maskers]
     fwd = np.zeros((rows, cols), np.float32)
     for m in masks:
         fwd = fwd + m
@@ -58,8 +59,11 @@ def test_masks_cancel_bit_exact(n_members, rnd, rows, cols):
     assert fwd.dtype == np.float32 and rev.dtype == np.float32
     assert np.all(fwd == 0.0)
     assert np.all(rev == 0.0)
-    # ... and the masks are not trivially zero (masking actually hides)
-    assert max(float(np.abs(m).max()) for m in masks) > 0.1
+    # ... and the masks are not trivially zero (masking actually hides).
+    # Judged on 256 entries of the same round: a mask of one entry is
+    # below 0.1 for 8% of fresh keys, 256 of them for none in practice
+    probe = [m.mask(rnd, (256,)) for m in maskers]
+    assert max(float(np.abs(m).max()) for m in probe) > 0.1
 
 
 @given(st.integers(2, 4), st.integers(0, 500), st.integers(0, 2**31))
